@@ -37,6 +37,8 @@ def main() -> None:
     args = ap.parse_args()
     only = [s for s in args.only.split(",") if s]
 
+    from repro.compile_cache import enable_compile_cache
+    print(f"compile cache: {enable_compile_cache()}", file=sys.stderr)
     print("name,us_per_call,derived")
     failures = []
     for name, mod_name in BENCHES:

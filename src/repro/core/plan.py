@@ -1421,7 +1421,7 @@ class StagedQueryPlan:
                         wrap_sig: Optional[Tuple] = None) -> Callable:
         """Stream-axis-aware variant of ``_get_step``: the same fused
         stage step vmapped over a leading (S,) stream axis, optionally
-        wrapped by ``shard_wrap`` (a ``distributed.sharding.shard_map``
+        wrapped by ``shard_wrap`` (a ``jax.shard_map``
         closure over a device mesh's stream axis) before jitting, so S
         streams' stage work runs as ONE dispatched program — per device,
         a contiguous block of streams — instead of S host round-trips.

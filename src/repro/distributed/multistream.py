@@ -17,12 +17,12 @@ stage steps as single fused programs over the stack
 
 - **shard_map over the stream axis.**  With a ``("stream",)`` device
   mesh (``distributed.sharding.stream_mesh``), each group step is
-  wrapped in the repo's version-tolerant ``shard_map`` shim: device d
+  wrapped in ``jax.shard_map``: device d
   evaluates its block of streams, one dispatch for the whole fleet
   slice.  The PartitionSpec comes from the ordinary sharding rules
   (``spec_for`` — so an S not divisible by the device count falls back
-  to replication instead of erroring, the same divisibility discipline
-  as every other axis).
+  to replication, with a warning, instead of erroring: the same
+  divisibility discipline as every other axis).
 
 - **Double-buffered prefetch.**  ``run_chunk(idx, next_idx)`` stages
   chunk k+1's stacked ``FilterOutputs`` onto the mesh with
@@ -64,6 +64,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import time
+import warnings
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -143,10 +144,11 @@ class ShardedPlanGroupEngine:
     fused sharded step per executed tier.
 
     ``mesh`` (a ``("stream",)`` mesh from ``sharding.stream_mesh``)
-    turns the group steps into ``shard_map`` programs; without it (or
-    when S doesn't divide over the mesh axis — ``spec_for`` falls back
-    to replication) the steps run as plain vmapped programs on the
-    default device, which is also the bit-identity reference path.
+    turns the group steps into ``shard_map`` programs; without it (or,
+    with a warning, when S doesn't divide over the mesh axis —
+    ``spec_for`` falls back to replication) the steps run as plain
+    vmapped programs on the default device, which is also the
+    bit-identity reference path.
 
     ``slot_stats`` is the shared population ledger (typically the
     registry's, possibly gossip-warm-started): it orders the stages at
@@ -215,13 +217,18 @@ class ShardedPlanGroupEngine:
             if len(spec) and spec[0] is not None:
                 from jax.sharding import NamedSharding
                 self._sharding = NamedSharding(mesh, spec)
-                self.shard_wrap = lambda fn: SH.shard_map(
+                self.shard_wrap = lambda fn: jax.shard_map(
                     fn, mesh=mesh, in_specs=spec, out_specs=spec,
                     check_vma=False)
                 self.wrap_sig = ("mesh",
                                  tuple(d.id for d in mesh.devices.flat),
                                  tuple(mesh.axis_names),
                                  tuple(mesh.devices.shape), repr(spec))
+            else:
+                warnings.warn(f"{S} streams do not divide over the "
+                              f"{mesh.devices.size}-device stream mesh: "
+                              f"the group steps run unsharded on one "
+                              f"device")
 
     @staticmethod
     def _key(idx: np.ndarray) -> Tuple[int, int, int]:

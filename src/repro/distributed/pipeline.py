@@ -90,8 +90,7 @@ def make_pipelined_forward(cfg: ModelConfig, mesh: Mesh, *,
         run = functools.partial(pipeline_forward, cfg=cfg,
                                 axis_name=pipe_axis, n_stages=n_stages,
                                 n_micro=n_micro)
-        from repro.distributed.sharding import shard_map
-        return shard_map(
+        return jax.shard_map(
             run, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: pspec, stack,
                                    is_leaf=lambda v: hasattr(v, "shape")),

@@ -102,8 +102,7 @@ def sharded_decode_attention(
     body = functools.partial(_body, axis=seq_axis, s_local=s_local,
                              scale=1.0 / math.sqrt(hd))
     cache_spec = P(bspec, seq_axis)
-    from repro.distributed.sharding import shard_map
-    out, kc, vc = shard_map(
+    out, kc, vc = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec), P(bspec), P(bspec),
                   cache_spec, cache_spec, P()),

@@ -20,7 +20,6 @@ Alternative layouts for §Perf hillclimbing are expressed as rule overrides
 from __future__ import annotations
 
 import dataclasses
-import inspect
 from typing import Any, Dict, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
@@ -29,23 +28,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 Axis = Union[None, str, Tuple[str, ...]]
 
-
-def shard_map(fn, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-tolerant ``shard_map``: jax>=0.6 exposes ``jax.shard_map``
-    with ``check_vma``; 0.4/0.5 only have the experimental spelling with
-    ``check_rep``.  All repo call sites route through here."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        # probe the kwarg spelling instead of try/except so a caller's
-        # genuine TypeError isn't swallowed and retried
-        if "check_vma" in inspect.signature(sm).parameters:
-            return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
 
 DEFAULT_RULES: Dict[str, Axis] = {
     "batch": ("pod", "data"),

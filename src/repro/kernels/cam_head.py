@@ -35,7 +35,8 @@ def _kernel(f_ref, w_ref, b_ref, counts_ref, cam_ref, acc_ref, *,
     f = f_ref[0].astype(jnp.float32)                   # (g2, dT)
     w = w_ref[...].astype(jnp.float32)                 # (dT, C)
     acc_ref[...] += jax.lax.dot_general(
-        f, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        f, w, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
     @pl.when(idx == n_d - 1)
     def _finish():
@@ -43,13 +44,16 @@ def _kernel(f_ref, w_ref, b_ref, counts_ref, cam_ref, acc_ref, *,
         cam_ref[0] = cam.astype(cam_ref.dtype)
         pooled = cam.sum(axis=0, keepdims=True) / g2   # (1, C)
         counts_ref[0] = jax.nn.relu(
-            pooled + b_ref[...].astype(jnp.float32))[0].astype(counts_ref.dtype)
+            pooled + b_ref[...].astype(jnp.float32)).astype(counts_ref.dtype)
 
 
 def cam_head_bgd(feat: jax.Array, w: jax.Array, b: jax.Array, *,
                  d_block: int = 512,
                  interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """feat: (B, g2, D); w: (D, C); b: (C,) -> (counts (B,C), cam (B,g2,C))."""
+    """feat: (B, g2, D); w: (D, C); b: (C,) -> (counts (B,C), cam (B,g2,C)).
+
+    ``d_block`` must divide D and, on TPU, be a multiple of 128 or D
+    itself (``ops.cam_head_block`` picks one)."""
     B, g2, D = feat.shape
     C = w.shape[1]
     d_block = min(d_block, D)
@@ -65,15 +69,17 @@ def cam_head_bgd(feat: jax.Array, w: jax.Array, b: jax.Array, *,
             pl.BlockSpec((d_block, C), lambda b_, id_: (id_, 0)),
             pl.BlockSpec((1, C), lambda b_, id_: (0, 0)),
         ],
+        # counts ride a (B, 1, C) array so each block's trailing dims equal
+        # the array's: TPU blocks must tile by (8, 128) or span the dims
         out_specs=[
-            pl.BlockSpec((1, C), lambda b_, id_: (b_, 0)),
+            pl.BlockSpec((1, 1, C), lambda b_, id_: (b_, 0, 0)),
             pl.BlockSpec((1, g2, C), lambda b_, id_: (b_, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, C), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, C), jnp.float32),
             jax.ShapeDtypeStruct((B, g2, C), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((g2, C), jnp.float32)],
         interpret=interpret,
     )(feat, w, b.reshape(1, C))
-    return counts, cam
+    return counts.reshape(B, C), cam

@@ -1,9 +1,16 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-On TPU the kernels compile natively; on this CPU container they run in
-``interpret=True`` mode (the kernel body executed op-by-op), which is what
-the per-kernel allclose tests validate.  Layout adapters live here so the
-model code keeps its natural (B, S, H, hd) activations.
+On TPU every wrapper calls the compiled kernel.  tests/test_chip_compile.py
+compiles the served path's kernels (CAM head, both spatial reductions) for
+a described v5e at real widths, and chip_smoke.py checks them on the chip
+against their references.  On the CPU backend the CAM head and the
+language-model kernels run in ``interpret=True`` mode (the kernel body
+executed op-by-op; tests/test_kernels.py checks that against ``ref``),
+while the spatial statistics take a pure-JAX projection reduction and
+never enter the kernel.  The language-model wrappers (flash, decode,
+rwkv6) fall back to the ``ref`` oracle when a sequence does not tile by
+the block size; ``cam_head`` raises instead.  Layout adapters live here so
+the model code keeps its natural (B, S, H, hd) activations.
 """
 from __future__ import annotations
 
@@ -64,15 +71,27 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, H, hd)
 
 
+def cam_head_block(D: int, d_block: int = 512) -> int:
+    """Feature-axis tile of ``cam_head_bgd``: all of D when it fits in
+    ``d_block``, else the largest multiple of 128 up to ``d_block`` that
+    divides D (a TPU block's lane dim must tile by 128 or span the array).
+    Raises ValueError when no such tile exists."""
+    if D <= d_block:
+        return D
+    for db in range(d_block - d_block % 128, 0, -128):
+        if D % db == 0:
+            return db
+    raise ValueError(f"cam_head: no feature tile <= {d_block} that is a "
+                     f"multiple of 128 divides D={D}")
+
+
 @functools.partial(jax.jit, static_argnames=("d_block",))
 def cam_head(feat: jax.Array, w: jax.Array, b: jax.Array, *,
              d_block: int = 512) -> Tuple[jax.Array, jax.Array]:
     """feat: (B, g, g, D); w: (D, C); b: (C,) -> (counts, cam (B,g,g,C))."""
     B, g, _, D = feat.shape
     C = w.shape[1]
-    db = min(d_block, D)
-    if D % db:
-        return ref.cam_head_ref(feat, w, b)
+    db = cam_head_block(D, d_block)
     counts, cam = cam_head_bgd(feat.reshape(B, g * g, D), w, b,
                                d_block=db, interpret=_interpret())
     return counts, cam.reshape(B, g, g, C)

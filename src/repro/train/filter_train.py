@@ -55,15 +55,23 @@ def init_filter_model(rng, trunk_cfg: ModelConfig, spec: BranchSpec,
     }
 
 
+def filter_tap(p: Params, trunk_cfg: ModelConfig, spec: BranchSpec,
+               embeds: jax.Array) -> jax.Array:
+    """embeds: (B, P, d_in) stub-frontend patches -> the trunk activations
+    after layer ``spec.layer`` (B, P, d_model), which the branch consumes."""
+    x = jnp.einsum("bpd,de->bpe", embeds.astype(jnp.float32), p["proj"])
+    x = x + p["pos"][: x.shape[1]][None]
+    return M.forward(p["trunk"], trunk_cfg, tokens=None, embeds=x,
+                     tap_layer=spec.layer, stop_at_tap=True,
+                     causal=False).tap
+
+
 def filter_forward(p: Params, trunk_cfg: ModelConfig, spec: BranchSpec,
                    embeds: jax.Array, use_kernel: bool = False
                    ) -> F.FilterOutputs:
     """embeds: (B, P, d_in) stub-frontend patches -> FilterOutputs."""
-    x = jnp.einsum("bpd,de->bpe", embeds.astype(jnp.float32), p["proj"])
-    x = x + p["pos"][: x.shape[1]][None]
-    out = M.forward(p["trunk"], trunk_cfg, tokens=None, embeds=x,
-                    tap_layer=spec.layer, stop_at_tap=True, causal=False)
-    return F.branch_apply(p["branch"], out.tap, spec,
+    tap = filter_tap(p, trunk_cfg, spec, embeds)
+    return F.branch_apply(p["branch"], tap, spec,
                           **({"use_kernel": use_kernel}
                              if spec.kind == "ic" else {}))
 

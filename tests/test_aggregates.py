@@ -114,9 +114,8 @@ def test_distributed_reduce_matches_merge():
         return out.n, out.mean, out.M2
 
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.distributed.sharding import shard_map
     mesh = jax.make_mesh((1,), ("i",))
-    g = shard_map(f, mesh=mesh, in_specs=(P(), P(), P()),
+    g = jax.shard_map(f, mesh=mesh, in_specs=(P(), P(), P()),
                   out_specs=(P(), P(), P()), check_vma=False)
     n2, m2, M22 = g(acc.n, acc.mean, acc.M2)
     np.testing.assert_allclose(m2, acc.mean, atol=1e-6)
@@ -128,8 +127,7 @@ def test_accumulator_init_dtypes_consistent():
     with always-f32 moments)."""
     acc = AGG.CVAccumulator.init(2)
     assert acc.n.dtype == acc.mean.dtype == acc.M2.dtype
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         acc64 = AGG.CVAccumulator.init(2)
         assert acc64.n.dtype == acc64.mean.dtype == acc64.M2.dtype
         assert acc64.n.dtype == jnp.float64
@@ -141,14 +139,13 @@ def test_accumulator_long_stream_matches_mcv():
     data — the float32 accumulator drifted (Welford co-moments cancel
     catastrophically once mean*n dwarfs the per-batch deltas) and lost
     exact integer counting of n past 2^24."""
-    from jax.experimental import enable_x64
     rng = np.random.default_rng(7)
     n_chunks, chunk = 60, 4096                       # ~250k frames
     # large common mean maximizes f32 cancellation in the co-moments
     x = rng.normal(0, 1, n_chunks * chunk)
     y = 1e4 + 0.8 * x + rng.normal(0, 0.5, n_chunks * chunk)
     z = (1e4 + x)[:, None]
-    with enable_x64():
+    with jax.enable_x64(True):
         acc = AGG.CVAccumulator.init(1)
         for k in range(n_chunks):
             sl = slice(k * chunk, (k + 1) * chunk)

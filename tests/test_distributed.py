@@ -89,38 +89,6 @@ def test_spec_duplicate_axis_suppression_tuples():
     assert s2 == P("data")
 
 
-def test_shard_map_kwarg_probe_shim(monkeypatch):
-    seen = {}
-
-    def vma_style(fn, *, mesh, in_specs, out_specs, check_vma):
-        seen["kw"] = ("check_vma", check_vma)
-        return fn
-
-    def rep_style(fn, *, mesh, in_specs, out_specs, check_rep):
-        seen["kw"] = ("check_rep", check_rep)
-        return fn
-
-    f = lambda x: x                                           # noqa: E731
-    monkeypatch.setattr(jax, "shard_map", vma_style, raising=False)
-    assert SH.shard_map(f, mesh="m", in_specs=P(), out_specs=P(),
-                        check_vma=False) is f
-    assert seen["kw"] == ("check_vma", False)
-    # jax 0.4/0.5 spelling: the flag is forwarded as check_rep
-    monkeypatch.setattr(jax, "shard_map", rep_style, raising=False)
-    assert SH.shard_map(f, mesh="m", in_specs=P(), out_specs=P()) is f
-    assert seen["kw"] == ("check_rep", True)
-
-
-def test_shard_map_experimental_fallback(monkeypatch):
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    pytest.importorskip("jax.experimental.shard_map")
-    mesh = Mesh(np.asarray(jax.devices()[:1]), ("stream",))
-    f = SH.shard_map(lambda x: x * 2, mesh=mesh, in_specs=P(),
-                     out_specs=P(), check_vma=False)
-    np.testing.assert_array_equal(np.asarray(f(jnp.arange(4))),
-                                  np.arange(4) * 2)
-
-
 def test_stream_mesh():
     m = SH.stream_mesh()
     assert m.axis_names == ("stream",)
@@ -165,8 +133,7 @@ def test_allreduce_compressed_single_device():
     def f(gg, ee):
         return COMP.allreduce_compressed(gg, ee, "data")
 
-    from repro.distributed.sharding import shard_map
-    out, new_err = shard_map(
+    out, new_err = jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
         check_vma=False)(g, err)
     np.testing.assert_allclose(out["w"], g["w"], atol=0.01)

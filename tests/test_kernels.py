@@ -74,6 +74,20 @@ def test_cam_head_sweep(g, D, C):
     np.testing.assert_allclose(m1, m2, atol=1e-3)
 
 
+@pytest.mark.parametrize("D,d_block,want", [
+    (32, 512, 32), (256, 512, 256), (1024, 512, 512), (768, 512, 384),
+    (640, 512, 128), (1000, 512, None)])
+def test_cam_head_block(D, d_block, want):
+    """The feature tile divides D and tiles the lane axis by 128 (or is
+    all of D); with no such tile the wrapper raises instead of silently
+    taking the reference path."""
+    if want is None:
+        with pytest.raises(ValueError, match="D=1000"):
+            ops.cam_head_block(D, d_block)
+    else:
+        assert ops.cam_head_block(D, d_block) == want
+
+
 @pytest.mark.parametrize("g,C", [(8, 4), (16, 8), (56, 8)])
 def test_spatial_stats_sweep(g, C):
     gl = jax.random.normal(jax.random.PRNGKey(4), (3, g, g, C)) * 3
