@@ -160,7 +160,6 @@ def _worker_group(n_frames, warm_frames, shard):
         HoppingWindow(size=WINDOW, advance=WINDOW), BATCH,
         [f"cam{i}" for i in range(S)], n_slots=n_slots)
     ex.run(warm_frames)                 # compile + prefetch path warm
-    ex.chunk_latencies_s.clear()
 
     t0 = time.perf_counter()
     ex.run(n_frames)
@@ -170,8 +169,6 @@ def _worker_group(n_frames, warm_frames, shard):
     report = engine.staged.last_report
     res = {"mode": "group", "fps": S * n_frames / wall, "wall_s": wall,
            "frames": S * n_frames, "sharded": engine.shard_wrap is not None,
-           "latency_p50_ms": ex.latency_percentile(50) * 1e3,
-           "latency_p95_ms": ex.latency_percentile(95) * 1e3,
            "chunk_batch": report.batch if report else None,
            "cost_run": report.cost_run if report else None,
            "cost_total": report.cost_total if report else None,
@@ -269,7 +266,6 @@ def _worker_temporal(n_frames, warm_frames, shard):
         HoppingWindow(size=WINDOW, advance=WINDOW), BATCH,
         stream_ids, n_slots=n_slots)
     ex.run(warm_frames)                 # compile scan + staged steps
-    ex.chunk_latencies_s.clear()
     ex._engine.temporal_stats.__init__()    # steady-state stats only
 
     t0 = time.perf_counter()
@@ -327,8 +323,6 @@ def _worker_temporal(n_frames, warm_frames, shard):
     return {"mode": "temporal", "fps": S * n_frames / wall,
             "wall_s": wall, "frames": S * n_frames,
             "sharded": ex._engine.shard_wrap is not None,
-            "latency_p50_ms": ex.latency_percentile(50) * 1e3,
-            "latency_p95_ms": ex.latency_percentile(95) * 1e3,
             "identity_streams": S,
             "frames_in": ts.frames_in,
             "frames_skipped": ts.frames_skipped,
@@ -396,8 +390,7 @@ def run(smoke: bool = False) -> dict:
     emit("multi_stream_serving/group_1dev", 1e6 / group1["fps"],
          f"fps={group1['fps']:.0f};stacking={stacking:.2f}x")
     emit("multi_stream_serving/group_8dev", 1e6 / group8["fps"],
-         f"fps={group8['fps']:.0f};speedup={speedup:.2f}x;"
-         f"p95_ms={group8['latency_p95_ms']:.1f}")
+         f"fps={group8['fps']:.0f};speedup={speedup:.2f}x")
     emit("multi_stream_serving/fleet_temporal_8dev", 1e6 / tempo8["fps"],
          f"fps={tempo8['fps']:.0f};"
          f"skipped={tempo8['frames_skipped']}/{tempo8['frames_in']};"
@@ -407,8 +400,7 @@ def run(smoke: bool = False) -> dict:
           f"({stacking:.2f}x — stacking-only ablation)")
     print(f"group  8dev : {group8['fps']:10.0f} frames/s "
           f"({speedup:.2f}x vs serial 1dev; sharded="
-          f"{group8['sharded']}; chunk p50={group8['latency_p50_ms']:.1f}ms "
-          f"p95={group8['latency_p95_ms']:.1f}ms)")
+          f"{group8['sharded']})")
     print(f"temporal8dev: {tempo8['fps']:10.0f} frames/s "
           f"(answers == serial for {tempo8['identity_streams']} streams; "
           f"frames skipped {tempo8['frames_skipped']}/"

@@ -140,6 +140,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import costmodel as CM
 from repro.core import query as Q
 from repro.core.cascade import compact_indices
@@ -985,6 +986,8 @@ class StagedQueryPlan:
         self._wrap_refs: List = []  # keep unsigned shard_wraps alive so
         #                             their id()-based keys stay unique
         self._trace_count = 0       # lifetime traces paid by THIS plan
+        # the owning fleet engine's counters (host fetches, steps built)
+        self.counters: Optional[tracing.EngineCounters] = None
         self.last_report: Optional[StageReport] = None
         self._pending: Optional[Tuple[
             List[Tuple[np.ndarray, jax.Array, int]],
@@ -1252,10 +1255,7 @@ class StagedQueryPlan:
                 return (leaf_vals, value, decided, undec,
                         (vals & valid[:, None]).sum(0))
 
-        step = jax.jit(step_fn)
-        self._trace_count += 1
-        self.step_cache.put(key, step)
-        return step
+        return self._built(key, si, step_fn)
 
     # -- execution --------------------------------------------------------
 
@@ -1492,8 +1492,15 @@ class StagedQueryPlan:
         grp = jax.vmap(step_fn)
         if shard_wrap is not None:
             grp = shard_wrap(grp)
-        step = jax.jit(grp)
+        return self._built(key, si, grp)
+
+    def _built(self, key: Tuple, si: int, fn: Callable) -> Callable:
+        """Jit ``fn`` as stage ``si``'s step (device program
+        ``jit_plan_<stage>``) and cache it under ``key``."""
+        step = jax.jit(tracing.named(fn, f"plan_{self.stages[si].name}"))
         self._trace_count += 1
+        if self.counters is not None:
+            self.counters.steps_built += 1
         self.step_cache.put(key, step)
         return step
 
@@ -1619,46 +1626,48 @@ class StagedQueryPlan:
                     f"stage {st.name!r} has Spatial/Region leaves of an "
                     f"undecided query but the filter head emits no grid "
                     f"(OD-COF)")
-            n_rows = undecided_rows.sum(1)              # (S,)
-            worst = int(n_rows.max())
-            if worst >= B:
-                bucket = B                              # full-batch step
-            else:
-                bucket = max(1, int(self.min_bucket))
-                while bucket < worst:
-                    bucket <<= 1
-                bucket = min(bucket, B)
-            if bucket >= B:
-                body = self._body_for(si, None)
-                step = self._get_group_step(si, ran, None, body, S,
-                                            shard_wrap, wrap_sig)
-                leaf_vals, value, decided, undec, counts = step(
-                    outs, leaf_vals, presumed_dev)
-                rows_eval = B
-            else:
-                body = self._body_for(si, bucket)
-                step = self._get_group_step(si, ran, bucket, body, S,
-                                            shard_wrap, wrap_sig)
-                # per-stream undecided rows padded (compact_indices
-                # discipline: repeat the last survivor so duplicate
-                # scatters are benign) to the GROUP bucket
-                idx = np.zeros((S, bucket), np.int32)
-                for s in range(S):
-                    rows_s = np.nonzero(undecided_rows[s])[0]
-                    n = rows_s.size
-                    idx[s, :n] = rows_s
-                    idx[s, n:] = rows_s[-1] if n else 0
-                leaf_vals, value, decided, undec, counts = step(
-                    outs, leaf_vals, value, decided, jnp.asarray(idx),
-                    jnp.asarray(n_rows.astype(np.int32)), presumed_dev)
-                rows_eval = bucket
-            if rows_eval == B:
-                # full-batch group evaluation: S·B unconditional frames
-                # feed the per-slot ledger (compacted steps stay out —
-                # same conditioning argument as the serial path)
-                pending.append((self._stage_slots(si), counts.sum(0),
-                                S * B))
-            undec = np.asarray(undec)       # ONE (S, D + B) fetch/stage
+            with tracing.span(f"repro.plan.tier.{st.name}"):
+                n_rows = undecided_rows.sum(1)              # (S,)
+                worst = int(n_rows.max())
+                if worst >= B:
+                    bucket = B                              # full-batch step
+                else:
+                    bucket = max(1, int(self.min_bucket))
+                    while bucket < worst:
+                        bucket <<= 1
+                    bucket = min(bucket, B)
+                if bucket >= B:
+                    body = self._body_for(si, None)
+                    step = self._get_group_step(si, ran, None, body, S,
+                                                shard_wrap, wrap_sig)
+                    leaf_vals, value, decided, undec, counts = step(
+                        outs, leaf_vals, presumed_dev)
+                    rows_eval = B
+                else:
+                    body = self._body_for(si, bucket)
+                    step = self._get_group_step(si, ran, bucket, body, S,
+                                                shard_wrap, wrap_sig)
+                    # per-stream undecided rows padded (compact_indices
+                    # discipline: repeat the last survivor so duplicate
+                    # scatters are benign) to the GROUP bucket
+                    idx = np.zeros((S, bucket), np.int32)
+                    for s in range(S):
+                        rows_s = np.nonzero(undecided_rows[s])[0]
+                        n = rows_s.size
+                        idx[s, :n] = rows_s
+                        idx[s, n:] = rows_s[-1] if n else 0
+                    leaf_vals, value, decided, undec, counts = step(
+                        outs, leaf_vals, value, decided, jnp.asarray(idx),
+                        jnp.asarray(n_rows.astype(np.int32)), presumed_dev)
+                    rows_eval = bucket
+                if rows_eval == B:
+                    # full-batch group evaluation: S·B unconditional frames
+                    # feed the per-slot ledger (compacted steps stay out —
+                    # same conditioning argument as the serial path)
+                    pending.append((self._stage_slots(si), counts.sum(0),
+                                    S * B))
+                # ONE (S, D + B) fetch per stage
+                undec = tracing.to_host(undec, "plan_undecided", self.counters)
             undecided_cols, undecided_rows = undec[:, :D], undec[:, D:]
             stage_rows.append((st.name, rows_eval * S, S * B,
                                int(n_rows.sum()),
@@ -1693,10 +1702,16 @@ class StagedQueryPlan:
         ``predicted_batch_cost``."""
         if not self._pending:
             return
+        with tracing.span("repro.plan.flush_stats"):
+            self._flush(stats)
+
+    def _flush(self, stats) -> None:
         pending, stage_rows = self._pending
         self._pending = None
         if pending:
-            counts = np.asarray(jnp.concatenate([c for _, c, _ in pending]))
+            counts = tracing.to_host(
+                jnp.concatenate([c for _, c, _ in pending]), "plan_counts",
+                self.counters)
             off = 0
             for slots, _, seen in pending:
                 stats.observe_many(

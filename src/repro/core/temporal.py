@@ -78,6 +78,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.core import query as Q
 from repro.core.stepcache import StepCache, content_digest
 
@@ -583,7 +584,8 @@ class TemporalProgram:
         key = ("tstep", self.program_sig, int(B))
         step = self._step_cache.get(key)
         if step is None:
-            step = jax.jit(self.build_scan_fn())
+            step = jax.jit(tracing.named(self.build_scan_fn(),
+                                         "temporal_scan"))
             self._step_cache.put(key, step)
             self.scan_traces += 1
         return step
@@ -705,7 +707,9 @@ def advance_group(programs: Sequence[TemporalProgram],
                   signals: np.ndarray, *,
                   step_cache: Optional[StepCache] = None,
                   shard_wrap: Optional[Callable] = None,
-                  wrap_sig: Optional[Tuple] = None) -> np.ndarray:
+                  wrap_sig: Optional[Tuple] = None,
+                  counters: Optional[tracing.EngineCounters] = None
+                  ) -> np.ndarray:
     """Advance S structurally identical ``TemporalProgram`` windows by
     one (S, B, M) bool signal batch at once; returns the (S, B, N) bool
     per-frame query outputs.
@@ -723,7 +727,12 @@ def advance_group(programs: Sequence[TemporalProgram],
 
     Programs must share a content digest (same canonical queries), the
     same window position, and the same window length — the fleet engine
-    guarantees this by starting every stream's window together."""
+    guarantees this by starting every stream's window together.
+
+    Runs under the span ``repro.temporal.advance``; the scan's output
+    and each state leaf come back to the host as fetches of their own
+    (``repro.sync.temporal_state``), counted, with the steps built, in
+    the fleet engine's ``counters`` when given."""
     programs = list(programs)
     if not programs:
         raise ValueError("advance_group needs at least one program")
@@ -747,11 +756,18 @@ def advance_group(programs: Sequence[TemporalProgram],
         raise ValueError(
             f"advance past window end: pos={p0.pos} + B={B} > "
             f"window_len={p0.window_len} (call start_window)")
-    if p0.backend != "scan" or B == 0:
-        return np.stack([p.advance(signals[s])
-                         for s, p in enumerate(programs)])
+    with tracing.span("repro.temporal.advance"):
+        if p0.backend != "scan" or B == 0:
+            return np.stack([p.advance(signals[s])
+                             for s, p in enumerate(programs)])
+        return _advance_group_scan(programs, signals, step_cache,
+                                   shard_wrap, wrap_sig, counters)
 
+
+def _advance_group_scan(programs, signals, step_cache, shard_wrap,
+                        wrap_sig, counters) -> np.ndarray:
     import jax
+    p0, (S, B, _) = programs[0], signals.shape
     cache = step_cache if step_cache is not None else p0._step_cache
     if wrap_sig is not None:
         wrap_key: Any = wrap_sig
@@ -766,16 +782,19 @@ def advance_group(programs: Sequence[TemporalProgram],
         fn = jax.vmap(p0.build_scan_fn())
         if shard_wrap is not None:
             fn = shard_wrap(fn)
-        step = jax.jit(fn)
+        step = jax.jit(tracing.named(fn, "temporal_scan"))
         cache.put(key, step)
         p0.scan_traces += 1
+        if counters is not None:
+            counters.steps_built += 1
 
     dec_before = np.stack([p._q_dec for p in programs])
     state = tuple(np.stack(leaves) for leaves
                   in zip(*(p._state_tuple() for p in programs)))
     state2, out = step(state, signals)
-    out = np.array(out)
-    state2 = [np.asarray(leaf) for leaf in state2]
+    out, *state2 = tracing.to_host([out, *state2], "temporal_state",
+                                   counters)
+    out = out.copy()            # decided columns are written below
     for s, p in enumerate(programs):
         p._absorb_state([leaf[s] for leaf in state2])
         p.pos += B

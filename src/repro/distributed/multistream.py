@@ -71,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import query as Q
 from repro.core.filters import FilterOutputs
 from repro.core.plan import QueryPlan
@@ -165,7 +166,12 @@ class ShardedPlanGroupEngine:
     steps.  The mesh identity in those step keys is a *content* digest
     of the device assignment (``wrap_sig``), not the wrap closure's
     object identity, precisely so rebuilt engines over the same mesh
-    share steps."""
+    share steps.
+
+    ``counters`` (``tracing.EngineCounters``) counts the engine's chunks,
+    its device-to-host fetches (the plan's and the temporal scan's
+    included), prefetch hits and misses, and the jitted steps it built;
+    each chunk runs under the span ``repro.engine.run_chunk``."""
 
     def __init__(self, queries: Sequence, streams: Sequence[StreamContext],
                  fetch: Callable[[StreamContext, np.ndarray], FilterOutputs],
@@ -184,6 +190,7 @@ class ShardedPlanGroupEngine:
         self.restage_every = restage_every
         self.queries = tuple(queries)
         self._step_cache = step_cache
+        self.counters = tracing.EngineCounters()
         # temporal queries: plan over the deduped frame signals, keep
         # per-stream automaton state (shared structure, one window per
         # stream), advance all windows with one vmapped scan step
@@ -205,6 +212,7 @@ class ShardedPlanGroupEngine:
         self.staged = self.plan.build_staged(
             slot_stats, min_bucket=min_bucket, cost_model=cm,
             spatial_body=spatial_body, step_cache=step_cache)
+        self.staged.counters = self.counters
         self._chunks = 0
         self._next: Optional[Tuple[Tuple[int, int, int], FilterOutputs]] = \
             None
@@ -249,7 +257,36 @@ class ShardedPlanGroupEngine:
     def prefetch(self, idx: np.ndarray) -> None:
         """Stage a chunk's stacked inputs ahead of time (device_put is
         async — the transfer overlaps whatever is currently computing)."""
-        self._next = (self._key(idx), self._stack(idx))
+        with tracing.span("repro.engine.prefetch", frame=int(idx[0])):
+            self._next = (self._key(idx), self._stack(idx))
+
+    def _take(self, idx: np.ndarray) -> FilterOutputs:
+        """This chunk's stacked inputs: the prefetched ones when they are
+        this chunk's, else stacked now."""
+        nxt, self._next = self._next, None
+        if nxt is not None and nxt[0] == self._key(idx):
+            self.counters.prefetch_hits += 1
+            return nxt[1]
+        self.counters.prefetch_misses += 1
+        with tracing.span("repro.engine.stack", frame=int(idx[0])):
+            return self._stack(idx)
+
+    def _answer(self, value: jax.Array, next_idx: Optional[np.ndarray]
+                ) -> np.ndarray:
+        """Stage ``next_idx``'s inputs, then block on this chunk's answer
+        and fold the plan's statistics (re-staging every
+        ``restage_every`` chunks)."""
+        if next_idx is not None and next_idx.size:
+            self.prefetch(next_idx)         # overlaps the block below
+        ans = tracing.to_host(value, "answer", self.counters)
+        if self.slot_stats is not None:
+            self.staged.flush_stats(self.slot_stats)
+            self._chunks += 1
+            if self.restage_every and \
+                    self._chunks % self.restage_every == 0:
+                with tracing.span("repro.engine.restage"):
+                    self.staged.restage(self.slot_stats)
+        return ans
 
     def stage_order(self) -> List[str]:
         """Current stage execution order (warm-start observability)."""
@@ -271,26 +308,14 @@ class ShardedPlanGroupEngine:
                   next_idx: Optional[np.ndarray] = None) -> np.ndarray:
         """(S, B, N) bool answers for one chunk; double-buffers
         ``next_idx``'s transfer behind this chunk's evaluation."""
-        if self.temporal is not None:
-            return self._run_chunk_temporal(idx, next_idx)
-        if self._next is not None and self._next[0] == self._key(idx):
-            outs = self._next[1]
-        else:
-            outs = self._stack(idx)
-        self._next = None
-        value = self.staged.evaluate_group(outs,
-                                           shard_wrap=self.shard_wrap,
-                                           wrap_sig=self.wrap_sig)
-        if next_idx is not None and next_idx.size:
-            self.prefetch(next_idx)         # overlaps the block below
-        ans = np.asarray(value)             # block on this chunk
-        if self.slot_stats is not None:
-            self.staged.flush_stats(self.slot_stats)
-            self._chunks += 1
-            if self.restage_every and \
-                    self._chunks % self.restage_every == 0:
-                self.staged.restage(self.slot_stats)
-        return ans
+        self.counters.chunks += 1
+        with tracing.span("repro.engine.run_chunk", frame=int(idx[0])):
+            if self.temporal is not None:
+                return self._run_chunk_temporal(idx, next_idx)
+            value = self.staged.evaluate_group(self._take(idx),
+                                               shard_wrap=self.shard_wrap,
+                                               wrap_sig=self.wrap_sig)
+            return self._answer(value, next_idx)
 
     def _run_chunk_temporal(self, idx: np.ndarray,
                             next_idx: Optional[np.ndarray]) -> np.ndarray:
@@ -317,30 +342,18 @@ class ShardedPlanGroupEngine:
                 self.cost_model, batch=B)
             return advance_group(
                 progs, np.zeros((S, B, M), bool),
-                step_cache=self._step_cache,
-                shard_wrap=self.shard_wrap, wrap_sig=self.wrap_sig)
+                step_cache=self._step_cache, shard_wrap=self.shard_wrap,
+                wrap_sig=self.wrap_sig, counters=self.counters)
         suppressed = np.stack([p.suppressed_signals() for p in progs])
         ts.signal_evals_skipped += B * int(suppressed.sum())
-        if self._next is not None and self._next[0] == self._key(idx):
-            outs = self._next[1]
-        else:
-            outs = self._stack(idx)
-        self._next = None
         value = self.staged.evaluate_group(
-            outs, shard_wrap=self.shard_wrap, wrap_sig=self.wrap_sig,
+            self._take(idx), shard_wrap=self.shard_wrap,
+            wrap_sig=self.wrap_sig,
             presumed_decided=suppressed if suppressed.any() else None)
-        if next_idx is not None and next_idx.size:
-            self.prefetch(next_idx)         # overlaps the block below
-        masks = np.asarray(value)           # block on this chunk
         rep = self.staged.last_report
         if rep is not None:
             ts.cost_saved_model += rep.cost_presumed_saved
-        if self.slot_stats is not None:
-            self.staged.flush_stats(self.slot_stats)
-            self._chunks += 1
-            if self.restage_every and \
-                    self._chunks % self.restage_every == 0:
-                self.staged.restage(self.slot_stats)
+        masks = self._answer(value, next_idx)
         # suppressed columns carry UNSPECIFIED mask values (the staged
         # plan stopped evaluating them) — zero them before the automata;
         # every consumer of a suppressed signal is frozen or decided, so
@@ -348,7 +361,8 @@ class ShardedPlanGroupEngine:
         signals = masks & ~suppressed[:, None, :]
         return advance_group(
             progs, signals, step_cache=self._step_cache,
-            shard_wrap=self.shard_wrap, wrap_sig=self.wrap_sig)
+            shard_wrap=self.shard_wrap, wrap_sig=self.wrap_sig,
+            counters=self.counters)
 
 
 def plan_group_engine_factory(fetch, **engine_kw) -> Callable:
@@ -389,16 +403,17 @@ class MultiStreamExecutor:
     at the next chunk boundary exactly as in the single-stream executor
     (``slot_stats`` opt-in is by parameter name, same contract).
 
-    Per-stream ``StreamStats`` (frames seen/processed/dropped) and
-    per-chunk latency samples are kept exactly as ``StreamExecutor``
-    does for one stream; ``latency_percentile(p)`` reports the serving
-    percentile the fleet bench records.  With a ``StragglerPolicy``,
-    drop accounting runs per stream against the arrival clock (each
-    stream is charged an equal 1/S share of the chunk's wall time); a
-    behind stream's chunk results are discarded — its rows still ride
-    the stacked step (group shapes are uniform), but stale answers are
-    never reported, which is the monitoring semantics that matters at
-    the ingest boundary.
+    Per-stream ``StreamStats`` (frames seen/processed/dropped) are kept
+    exactly as ``StreamExecutor`` does for one stream.  Each chunk runs
+    under the span ``repro.executor.chunk`` (its first frame index as
+    ``frame``), and an engine rebuild under ``repro.executor.rebuild``;
+    a profiler capture times them (``repro.tracing``).  With a
+    ``StragglerPolicy``, drop accounting runs per stream against the
+    arrival clock (each stream is charged an equal 1/S share of the
+    chunk's wall time); a behind stream's chunk results are discarded —
+    its rows still ride the stacked step (group shapes are uniform), but
+    stale answers are never reported, which is the monitoring semantics
+    that matters at the ingest boundary.
 
     ``on_window(result)`` fires after each window with per-stream hit
     counts and may mutate the registry (mid-stream multiplexing).
@@ -420,7 +435,6 @@ class MultiStreamExecutor:
                                      base_seed=base_seed)
         self.stats: Dict[Any, StreamStats] = {
             c.stream_id: StreamStats() for c in self.streams}
-        self.chunk_latencies_s: List[float] = []
         self.rebuilds = 0
         self._epoch = -1
         self._engine = None
@@ -434,30 +448,29 @@ class MultiStreamExecutor:
 
     def _refresh(self):
         if self.registry.epoch != self._epoch:
-            items = self.registry.active()
-            self._qids = tuple(qid for qid, _ in items)
-            if not items:
-                self._engine = None
-            else:
-                queries = tuple(q for _, q in items)
-                kw = {}
-                if self._factory_takes_stats:
-                    kw["slot_stats"] = self.registry.slot_stats
-                if self._factory_takes_table:
-                    kw["leaf_table"] = self.registry.leaf_table
-                if self._factory_takes_cache:
-                    kw["step_cache"] = self.registry.step_cache
-                self._engine = self.engine_factory(queries, self.streams,
-                                                   **kw)
-            self._epoch = self.registry.epoch
-            self.rebuilds += 1
+            with tracing.span("repro.executor.rebuild",
+                              epoch=self.registry.epoch):
+                self._rebuild()
         return self._engine, self._qids
 
-    def latency_percentile(self, p: float) -> float:
-        """p-th percentile of per-chunk serving latency (seconds)."""
-        if not self.chunk_latencies_s:
-            return 0.0
-        return float(np.percentile(self.chunk_latencies_s, p))
+    def _rebuild(self) -> None:
+        items = self.registry.active()
+        self._qids = tuple(qid for qid, _ in items)
+        if not items:
+            self._engine = None
+        else:
+            queries = tuple(q for _, q in items)
+            kw = {}
+            if self._factory_takes_stats:
+                kw["slot_stats"] = self.registry.slot_stats
+            if self._factory_takes_table:
+                kw["leaf_table"] = self.registry.leaf_table
+            if self._factory_takes_cache:
+                kw["step_cache"] = self.registry.step_cache
+            self._engine = self.engine_factory(queries, self.streams,
+                                               **kw)
+        self._epoch = self.registry.epoch
+        self.rebuilds += 1
 
     def run(self, n_frames: int,
             on_window: Optional[Callable[[MultiWindowResult], None]] = None
@@ -478,43 +491,46 @@ class MultiStreamExecutor:
             # single-stream executor documents
             started = None
             for k, idx in enumerate(chunks):
-                engine, qids = self._refresh()
-                if engine is None:
-                    continue
-                if engine is not started:
-                    hook = getattr(engine, "on_window_start", None)
-                    if hook is not None:
-                        hook(lo, hi)
-                    started = engine
-                # drop decision at chunk arrival, against slack accrued
-                # so far — the StreamExecutor discipline, per stream
-                dropped = set()
-                for c in self.streams:
-                    self.stats[c.stream_id].frames_seen += idx.size
-                    if self.policy is not None \
-                            and budget[c.stream_id] < 0:
-                        dropped.add(c.stream_id)
-                        self.stats[c.stream_id].frames_dropped += idx.size
-                    budget[c.stream_id] += arrival
-                # the engine was possibly rebuilt this chunk: only hand
-                # it a prefetch target it will recognise next call
-                nxt = chunks[k + 1] if k + 1 < len(chunks) else None
-                t0 = time.perf_counter()
-                ans = engine.run_chunk(idx, nxt)    # (S, B, n_active)
-                dt = time.perf_counter() - t0
-                self.chunk_latencies_s.append(dt)
-                share = dt / max(len(self.streams), 1)
-                for c in self.streams:
-                    sid = c.stream_id
-                    if sid in dropped:
-                        continue        # stale results discarded
-                    budget[sid] -= share
-                    st = self.stats[sid]
-                    st.frames_processed += idx.size
-                    h = hits[sid]
-                    for qk, qid in enumerate(qids):
-                        h[qid] = h.get(qid, 0) \
-                            + int(ans[c.position, :, qk].sum())
+                with tracing.span("repro.executor.chunk",
+                                  frame=int(idx[0])):
+                    engine, qids = self._refresh()
+                    if engine is None:
+                        continue
+                    if engine is not started:
+                        hook = getattr(engine, "on_window_start", None)
+                        if hook is not None:
+                            hook(lo, hi)
+                        started = engine
+                    # drop decision at chunk arrival, against slack
+                    # accrued so far — the StreamExecutor discipline, per
+                    # stream
+                    dropped = set()
+                    for c in self.streams:
+                        st = self.stats[c.stream_id]
+                        st.frames_seen += idx.size
+                        if self.policy is not None \
+                                and budget[c.stream_id] < 0:
+                            dropped.add(c.stream_id)
+                            st.frames_dropped += idx.size
+                        budget[c.stream_id] += arrival
+                    # the engine was possibly rebuilt this chunk: only
+                    # hand it a prefetch target it will recognise next call
+                    nxt = chunks[k + 1] if k + 1 < len(chunks) else None
+                    t0 = time.perf_counter()
+                    ans = engine.run_chunk(idx, nxt)  # (S, B, n_active)
+                    share = (time.perf_counter() - t0) \
+                        / max(len(self.streams), 1)
+                    for c in self.streams:
+                        sid = c.stream_id
+                        if sid in dropped:
+                            continue        # stale results discarded
+                        budget[sid] -= share
+                        st = self.stats[sid]
+                        st.frames_processed += idx.size
+                        h = hits[sid]
+                        for qk, qid in enumerate(qids):
+                            h[qid] = h.get(qid, 0) \
+                                + int(ans[c.position, :, qk].sum())
             for c in self.streams:
                 self.stats[c.stream_id].windows += 1
             res = MultiWindowResult(span=(lo, hi), hits=hits,
@@ -526,11 +542,3 @@ class MultiStreamExecutor:
         for st in self.stats.values():
             st.wall_s = wall
         return results
-
-    @property
-    def aggregate_fps(self) -> float:
-        """Fleet-level processed frames per second of wall time."""
-        done = sum(st.frames_processed for st in self.stats.values())
-        wall = max((st.wall_s for st in self.stats.values()),
-                   default=0.0)
-        return done / max(wall, 1e-9)

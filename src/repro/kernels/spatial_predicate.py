@@ -55,6 +55,7 @@ def spatial_stats_bgc(grid_logits: jax.Array, *, tau: float = 0.2,
         out_specs=pl.BlockSpec((1, C, 5), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, C, 5), jnp.float32),
         interpret=interpret,
+        name="spatial_stats",
     )(flat)
 
 
@@ -96,6 +97,7 @@ def spatial_stats_rows_bgc(grid_logits: jax.Array, rows: jax.Array, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, C, 5), jnp.float32),
         interpret=interpret,
+        name="spatial_stats_rows",
     )(rows.astype(jnp.int32), flat)
 
 

@@ -72,6 +72,7 @@ def test_spatial_stats_bgc_compiles(one_chip):
     grid = _shape((B, G, G, C), jnp.float32, one_chip)
     hlo = _compile_hlo(lambda x: spatial_stats_bgc(x, interpret=False), grid)
     assert "tpu_custom_call" in hlo
+    assert "%spatial_stats." in hlo         # the kernel's name in the trace
 
 
 def test_spatial_stats_rows_bgc_compiles(one_chip):
@@ -81,6 +82,7 @@ def test_spatial_stats_rows_bgc_compiles(one_chip):
         lambda x, r: spatial_stats_rows_bgc(x, r, interpret=False),
         grid, rows)
     assert "tpu_custom_call" in hlo
+    assert "%spatial_stats_rows." in hlo
 
 
 def test_cam_head_bgd_compiles(one_chip):
@@ -129,3 +131,6 @@ def test_sharded_group_spatial_step_compiles(topo, monkeypatch):
                      _shape((S, st.plan.n_distinct), jnp.bool_, sh)
                      ).compile().as_text()
     assert "tpu_custom_call" in hlo
+    # the names the device trace shows: the tier's program, the kernel
+    assert hlo.startswith("HloModule jit_plan_spatial")
+    assert "spatial_stats" in hlo

@@ -177,9 +177,15 @@ def test_multistream_equals_serial_with_churn():
 
     registry = QueryRegistry()
     qids = [registry.register(q) for q in QUERIES[:3]]
-    ex = MultiStreamExecutor(
-        registry, plan_group_engine_factory(_make_fetch(data)),
-        window, batch, stream_ids, n_slots=2)
+    engines = []
+    base = plan_group_engine_factory(_make_fetch(data))
+
+    def factory(queries, streams, **kw):
+        engines.append(base(queries, streams, **kw))
+        return engines[-1]
+
+    ex = MultiStreamExecutor(registry, factory, window, batch, stream_ids,
+                             n_slots=2)
     results = ex.run(n_frames,
                      lambda res: schedule(res.span, registry, qids))
 
@@ -194,9 +200,16 @@ def test_multistream_equals_serial_with_churn():
         st = ex.stats[sid]
         assert st.frames_seen == st.frames_processed == 96
         assert st.frames_dropped == 0 and st.windows == 3
-    assert len(ex.chunk_latencies_s) == 6
-    assert ex.latency_percentile(95) >= ex.latency_percentile(50) > 0
-    assert ex.aggregate_fps > 0
+    # the engines' counters: every chunk served once, its stack either
+    # prefetched or built on arrival, each answered with at least the
+    # answer fetch and one tier's undecided fetch
+    counters = [e.counters for e in engines]
+    assert len(engines) == ex.rebuilds
+    assert sum(c.chunks for c in counters) == 6
+    for c in counters:
+        assert c.prefetch_hits + c.prefetch_misses == c.chunks
+        assert c.host_fetches >= 2 * c.chunks
+    assert counters[0].steps_built > 0
 
 
 def test_multistream_empty_registry_serves_nothing():
@@ -533,6 +546,145 @@ def test_group_engine_temporal_skip_and_stats():
     assert ts.frames_skipped == S * (W - B)
     assert ts.cost_saved_model > 0.0 and ts.windows == 1
 
+
+
+# ---------------------------------------------------------------------------
+# Tracing: the engine's counters and the fleet path's spans
+# ---------------------------------------------------------------------------
+
+# frame-level queries are never window-decided, so no chunk takes the
+# temporal all-decided skip: every chunk runs the plan and the scan
+TRACED = QUERIES + (TQUERIES[0],)
+
+
+def _traced_fleet(S=3, n_frames=64, seed=21):
+    ctxs = route_streams([f"t{i}" for i in range(S)], 1)
+    data = {c.stream_id: _stream_data(seed + c.position, n_frames, 1.0)
+            for c in ctxs}
+    return ctxs, _make_fetch(data)
+
+
+def test_engine_counts_every_host_fetch_and_prefetch():
+    """Per chunk the engine fetches, counted where it happens: one
+    undecided-rows array per tier run, the plan's pass counts once when
+    a tier ran, the answer, and the scan's output and each of its state
+    leaves.  Within a window every chunk but the first was prefetched."""
+    B, W = 8, 32
+    ctxs, fetch = _traced_fleet()
+    # a bucket floor of B: every tier that runs is a full-batch step, so
+    # the pass counts are flushed whenever any tier ran
+    eng = ShardedPlanGroupEngine(TRACED, ctxs, fetch,
+                                 slot_stats=SlotStats(), min_bucket=B)
+    c = eng.counters
+    state_leaves = len(eng.temporal[0]._state_tuple())
+    eng.on_window_start(0, W)
+    chunks = [np.arange(b0, b0 + B) for b0 in range(0, W, B)]
+    tiers = 0
+    for k, idx in enumerate(chunks):
+        before = c.host_fetches
+        eng.run_chunk(idx, chunks[k + 1] if k + 1 < len(chunks) else None)
+        ran = len(eng.staged.last_report.ran)
+        tiers += ran
+        want = ran + (1 if ran else 0) + 1 + 1 + state_leaves
+        assert c.host_fetches - before == want, k
+    assert tiers > 0 and eng.temporal_stats.frames_skipped == 0
+    assert c.chunks == len(chunks)
+    assert c.prefetch_hits == len(chunks) - 1 and c.prefetch_misses == 1
+
+
+def test_second_identical_pass_builds_no_step():
+    """Every jitted step the fleet path needs is built on the first pass
+    over the footage; serving it again builds none."""
+    ctxs, fetch = _traced_fleet()
+    registry = QueryRegistry()
+    registry.register_many(TRACED)
+    ex = MultiStreamExecutor(registry, plan_group_engine_factory(fetch),
+                             HoppingWindow(size=32, advance=32), 8,
+                             [c.stream_id for c in ctxs], n_slots=1)
+    ex.run(64)
+    eng = ex._engine
+    built = eng.counters.steps_built
+    assert built >= 2          # plan tiers and the temporal scan
+    ex.run(64)
+    assert ex._engine is eng and eng.counters.steps_built == built
+    # 16 chunks in 4 windows; each window's first chunk was not prefetched
+    assert eng.counters.chunks == 16
+    assert eng.counters.prefetch_hits == 12
+    assert eng.counters.prefetch_misses == 4
+
+
+def test_fleet_path_span_nesting(monkeypatch):
+    """The spans the fleet path opens, each under the span of the layer
+    that calls it, with the chunk's first frame on the chunk spans."""
+    import contextlib
+
+    from repro import tracing
+    opened, stack = [], []
+
+    @contextlib.contextmanager
+    def span(name, **meta):
+        opened.append((stack[-1] if stack else None, name, meta))
+        stack.append(name)
+        try:
+            yield
+        finally:
+            stack.pop()
+
+    monkeypatch.setattr(tracing, "span", span)
+    ctxs, fetch = _traced_fleet()
+    registry = QueryRegistry()
+    registry.register_many(TRACED)
+    ex = MultiStreamExecutor(
+        registry, plan_group_engine_factory(fetch, restage_every=2),
+        HoppingWindow(size=32, advance=32), 8,
+        [c.stream_id for c in ctxs], n_slots=1)
+    ex.run(32)
+    assert not stack
+    parents = {}
+    for parent, name, _ in opened:
+        if name.startswith("repro.plan.tier."):
+            name = "repro.plan.tier.*"
+        if parent is not None and parent.startswith("repro.plan.tier."):
+            parent = "repro.plan.tier.*"
+        parents.setdefault(name, set()).add(parent)
+    run = "repro.engine.run_chunk"
+    assert parents == {
+        "repro.executor.chunk": {None},
+        "repro.executor.rebuild": {"repro.executor.chunk"},
+        run: {"repro.executor.chunk"},
+        "repro.engine.stack": {run},
+        "repro.engine.prefetch": {run},
+        "repro.plan.tier.*": {run},
+        "repro.sync.plan_undecided": {"repro.plan.tier.*"},
+        "repro.sync.answer": {run},
+        "repro.plan.flush_stats": {run},
+        "repro.sync.plan_counts": {"repro.plan.flush_stats"},
+        "repro.engine.restage": {run},
+        "repro.temporal.advance": {run},
+        "repro.sync.temporal_state": {"repro.temporal.advance"},
+    }
+    frames = [m["frame"] for _, n, m in opened
+              if n == "repro.executor.chunk"]
+    assert frames == [0, 8, 16, 24]
+    assert [m["frame"] for _, n, m in opened if n == run] == frames
+    assert {n for _, n, _ in opened if n.startswith("repro.plan.tier.")} \
+        == {f"repro.plan.tier.{s}" for s in ex._engine.stage_order()}
+
+
+def test_fleet_steps_are_named_by_stage():
+    """The jitted steps carry the names the device trace shows:
+    ``jit_plan_<stage>`` per plan tier, ``jit_temporal_scan``."""
+    ctxs, fetch = _traced_fleet()
+    eng = ShardedPlanGroupEngine(TRACED, ctxs, fetch,
+                                 slot_stats=SlotStats())
+    eng.on_window_start(0, 32)
+    eng.run_chunk(np.arange(8))
+    names = {cache._entries[k].__name__
+             for cache in (eng.staged.step_cache, eng.temporal[0]._step_cache)
+             for k in cache.keys()}
+    stages = {"plan_" + s.replace("@", "_") for s in eng.stage_order()}
+    assert names == stages | {"temporal_scan"}
+    assert {"plan_counts", "plan_spatial", "plan_region_r0"} <= names
 
 TEMPORAL_SHARDED_SCRIPT = r"""
 import os
