@@ -36,6 +36,13 @@ def constrain(x: jax.Array, kind: str = "act") -> jax.Array:
     return fn(x, kind) if fn is not None else x
 
 
+def partitioned() -> bool:
+    """Whether the step being traced is partitioned over a mesh: its
+    factory installed a sharder (every partitioned step is traced under
+    one).  Model code keeps such steps off single-device kernels."""
+    return _get() is not None
+
+
 # --- sequence-sharded decode attention (serving fast path) ----------------
 
 def _get_ds() -> Optional[dict]:

@@ -1,20 +1,34 @@
 """Public jit'd wrappers over the Pallas kernels.
 
 On TPU every wrapper calls the compiled kernel.  tests/test_chip_compile.py
-compiles the served path's kernels (CAM head, both spatial reductions) for
-a described v5e at real widths, and chip_smoke.py checks them on the chip
-against their references.  On the CPU backend the CAM head and the
-language-model kernels run in ``interpret=True`` mode (the kernel body
-executed op-by-op; tests/test_kernels.py checks that against ``ref``),
-while the spatial statistics take a pure-JAX projection reduction and
-never enter the kernel.  The language-model wrappers (flash, decode,
-rwkv6) fall back to the ``ref`` oracle when a sequence does not tile by
-the block size; ``cam_head`` raises instead.  Layout adapters live here so
-the model code keeps its natural (B, S, H, hd) activations.
+compiles the served path's kernels (CAM head, both spatial reductions, the
+flash attention at the filter trunks' widths) for a described v5e at real
+widths, and chip_smoke.py checks them on the chip against their
+references.  On the CPU backend the CAM head and the language-model
+kernels run in ``interpret=True`` mode (the kernel body executed
+op-by-op; tests/test_kernels.py checks that against ``ref``), while the
+spatial statistics take a pure-JAX projection reduction and never enter
+the kernel.
+
+``flash_attention`` takes any sequence lengths: its tiles come from the
+shapes (``flash_tiles``), and where no tile divides a length the queries
+and keys are padded to whole tiles (padded keys masked, padded rows
+dropped); it never falls back to the S x S reference.  It is
+differentiable (``jax.custom_vjp``; the backward is that of the XLA
+flash scan, ``kernels.xla_flash``, recomputed).  The model reaches it
+through ``models.layers._attend`` for uncached self-attention (no KV
+cache, a static zero offset, no soft-cap, no prefix) when the kernels
+run compiled and the step is not partitioned over a mesh; cached
+prefill and decode, soft-capped and prefix-LM calls, partitioned steps
+and every call on the CPU take the XLA flash scan.  The decode and rwkv6
+wrappers still fall back to the ``ref`` oracle when a sequence does not
+tile by the block size; ``cam_head`` raises instead.  Layout adapters
+live here so the model code keeps its natural (B, S, H, hd) activations.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -23,35 +37,111 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.cam_head import cam_head_bgd
 from repro.kernels.decode_attention import decode_attention_bkgd
-from repro.kernels.flash_attention import flash_attention_bhsd
+from repro.kernels.flash_attention import flash_attention_bsd
 from repro.kernels.rwkv6_scan import rwkv6_scan_bhtk
 from repro.kernels.spatial_predicate import (spatial_stats_bgc,
                                              spatial_stats_rows_bgc)
+from repro.kernels.xla_flash import flash_attention_xla
 
 
 def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "sliding_window",
-                                             "block_q", "block_k"))
+def kernels_compiled() -> bool:
+    """Whether the wrappers run the kernels compiled: on every backend but
+    the CPU, where they run in interpret mode.  A described-chip compile
+    steers this through ``_interpret``."""
+    return not _interpret()
+
+
+# Flash tiles: all keys in one step up to FLASH_KEYS (no mask, no online
+# rescaling), else blocks of FLASH_BLOCK_K keys; query rows as many as keep
+# one float32 score tile within FLASH_SCORE_BYTES, at most FLASH_ROWS.
+FLASH_KEYS, FLASH_BLOCK_K = 4096, 512
+FLASH_ROWS, FLASH_SCORE_BYTES = 512, 3 * 2**20
+
+
+def _tile(n: int, cap: int, align: int) -> Tuple[int, int]:
+    """(block, padded n) for an axis of length n cut into blocks of at
+    most ``cap``: n whole if it fits; else the largest multiple of
+    ``align`` in [cap/2, cap] that divides n; else n over ceil(n / cap)
+    equal blocks rounded up to ``align``, n padded to a whole number."""
+    if n <= cap:
+        return n, n
+    for b in range(cap - cap % align, cap // 2 - 1, -align):
+        if n % b == 0:
+            return b, n
+    nb = -(-n // cap)
+    b = -(-n // (nb * align)) * align
+    return b, nb * b
+
+
+def flash_tiles(Sq: int, Sk: int, masked: bool) -> Tuple[int, int, int, int]:
+    """(block_q, padded Sq, block_k, padded Sk) of the flash kernel.  A
+    causal or windowed call (``masked``) takes key blocks of
+    FLASH_BLOCK_K, so that the kernel can skip the masked ones."""
+    if not masked and Sk <= FLASH_KEYS:
+        bk, sk = Sk, Sk
+    else:
+        bk, sk = _tile(Sk, FLASH_BLOCK_K, 128)
+    lanes = -(-bk // 128) * 128
+    cap = max(16, min(FLASH_ROWS, FLASH_SCORE_BYTES // (4 * lanes)) // 16 * 16)
+    bq, sq = _tile(Sq, cap, 16)
+    return bq, sq, bk, sk
+
+
+def _flash_kernel(q, k, v, causal, sliding_window):
+    B, Sq, H, hd = q.shape
+    _, Sk, KV, _ = k.shape
+    if hd % 128 and 128 % hd:
+        raise ValueError(f"flash_attention: head dim {hd} neither divides "
+                         f"nor is a multiple of a 128-lane tile")
+    bq, sq, bk, sk = flash_tiles(Sq, Sk, causal or sliding_window is not None)
+
+    def lanes(x, S):                   # (B, S0, n, hd) -> (B, S, lanes)
+        n = x.shape[2] * hd            # narrow heads fill whole lane tiles
+        return jnp.pad(x.reshape(B, x.shape[1], n),
+                       ((0, 0), (0, S - x.shape[1]), (0, -n % 128)))
+
+    out = flash_attention_bsd(
+        lanes(q, sq), lanes(k, sk).transpose(0, 2, 1), lanes(v, sk),
+        head_dim=hd, group=H // KV, kv_len=Sk, scale=1.0 / math.sqrt(hd),
+        causal=causal, sliding_window=sliding_window, block_q=bq,
+        block_k=bk, interpret=_interpret())
+    return out[:, :Sq, :H * hd].reshape(B, Sq, H, hd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash(q, k, v, causal, sliding_window):
+    return _flash_kernel(q, k, v, causal, sliding_window)
+
+
+def _flash_fwd(q, k, v, causal, sliding_window):
+    return _flash_kernel(q, k, v, causal, sliding_window), (q, k, v)
+
+
+def _flash_bwd(causal, sliding_window, res, g):
+    """The VJP of the XLA flash path, recomputed from q, k, v."""
+    _, vjp = jax.vjp(functools.partial(flash_attention_xla, causal=causal,
+                                       sliding_window=sliding_window), *res)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "sliding_window"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
-                    sliding_window: Optional[int] = None,
-                    block_q: int = 128, block_k: int = 128) -> jax.Array:
-    """q: (B, Sq, H, hd); k, v: (B, Sk, KV, hd) -> (B, Sq, H, hd)."""
-    B, Sq, H, hd = q.shape
-    Sk = k.shape[1]
-    bq = min(block_q, Sq)
-    bk = min(block_k, Sk)
-    if Sq % bq or Sk % bk:
-        return ref.flash_attention_ref(q, k, v, causal=causal,
-                                       sliding_window=sliding_window)
-    out = flash_attention_bhsd(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=causal, sliding_window=sliding_window,
-        block_q=bq, block_k=bk, interpret=_interpret())
-    return out.transpose(0, 2, 1, 3)
+                    sliding_window: Optional[int] = None) -> jax.Array:
+    """q: (B, Sq, H, hd); k, v: (B, Sk, KV, hd) -> (B, Sq, H, hd).
+
+    Any lengths: tiles come from ``flash_tiles``, with the queries and
+    keys padded to whole tiles where no tile divides them (padded keys
+    are masked, padded query rows dropped).  Differentiable: the backward
+    is that of ``flash_attention_xla``, recomputed."""
+    return _flash(q, k, v, causal, sliding_window)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k",))

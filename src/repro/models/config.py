@@ -103,8 +103,8 @@ class ModelConfig:
     # --- numerics / performance -------------------------------------------
     dtype: str = "bfloat16"                  # activation/param dtype for lowering
     remat: str = "none"                      # none | full | selective
-    attn_impl: str = "xla_flash"             # xla_flash | xla_naive | pallas
-    attn_chunk: int = 512                    # kv-block for xla_flash scan
+    attn_impl: str = "flash"                 # flash | xla_naive | pallas (SSM kernel)
+    attn_chunk: int = 512                    # kv-block of the XLA flash scan
     scan_layers: bool = True                 # lax.scan over stacked layer params
     logits_softcap: float = 0.0              # grok-style tanh soft-capping (0 = off)
 

@@ -5,11 +5,10 @@ Initializers return ``{name: array}``; a parallel ``*_axes`` function
 returns the logical sharding axes with the identical tree structure
 (consumed by ``repro.distributed.sharding``).
 
-Attention implements the XLA "flash" path used for dry-run lowering:
-a macro-blocked, chunk-scanned online-softmax attention that never
-materialises the S x S score matrix and skips fully-masked causal
-blocks (static macro-block python loop -> exact-ish causal FLOPs).
-The Pallas TPU kernels in ``repro.kernels`` are the deployment path.
+Attention has two flash paths, chosen per call by ``_attend``: on a TPU,
+uncached self-attention of a step that is not partitioned runs in the
+Pallas kernel (``repro.kernels.flash_attention``); every other call runs
+the XLA flash scan (``repro.kernels.xla_flash``).
 """
 from __future__ import annotations
 
@@ -23,6 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.distributed import ctx
+from repro.kernels.xla_flash import NEG_INF, chunk_mask, flash_attention_xla
 from repro.models.config import Activation, ModelConfig
 
 Params = Dict[str, Any]
@@ -110,125 +110,8 @@ def sinusoid_pos(seq_len: int, d_model: int, dtype=jnp.float32) -> jax.Array:
 
 
 # --------------------------------------------------------------------------
-# Attention — XLA flash path
+# Attention — reference
 # --------------------------------------------------------------------------
-
-NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
-
-
-def _chunk_mask(q_pos, k_pos, *, causal, sliding_window, prefix_len,
-                k_valid=None):
-    """Boolean (..., Sq, Sk) mask: True = attend."""
-    m = jnp.ones(q_pos.shape + k_pos.shape, bool)
-    if causal:
-        c = q_pos[:, None] >= k_pos[None, :]
-        if prefix_len:
-            c = c | (k_pos[None, :] < prefix_len)       # PaliGemma prefix-LM
-        m = m & c
-    if sliding_window is not None:
-        m = m & (q_pos[:, None] - k_pos[None, :] < sliding_window)
-    if k_valid is not None:
-        m = m & k_valid[None, :]
-    return m
-
-
-def flash_attention_xla(
-    q: jax.Array,                 # (B, Sq, H, hd)
-    k: jax.Array,                 # (B, Sk, KV, hd)
-    v: jax.Array,                 # (B, Sk, KV, hd)
-    *,
-    causal: bool = True,
-    chunk: int = 512,
-    n_macro: int = 8,
-    sliding_window: Optional[int] = None,
-    prefix_len: int = 0,
-    q_offset: int = 0,
-    kv_len: Optional[jax.Array] = None,   # dynamic valid kv length (decode)
-    kv_pos: Optional[jax.Array] = None,   # explicit kv positions (ring cache)
-    softcap: float = 0.0,
-) -> jax.Array:
-    """Macro-blocked online-softmax attention.
-
-    Outer *static* python loop over ``n_macro`` q blocks lets each block scan
-    only its causal kv prefix (and only its sliding window), so lowered HLO
-    FLOPs approach the true causal cost instead of the full S^2.
-    """
-    B, Sq, H, hd = q.shape
-    _, Sk, KV, _ = k.shape
-    G = H // KV
-    scale = 1.0 / math.sqrt(hd)
-    qg = q.reshape(B, Sq, KV, G, hd)
-
-    n_macro = max(1, min(n_macro, Sq))
-    while Sq % n_macro:
-        n_macro -= 1
-    mq = Sq // n_macro
-    chunk = min(chunk, Sk)
-    while Sk % chunk:
-        chunk -= 1
-
-    static_offset = q_offset if isinstance(q_offset, int) else None
-
-    def one_macro(qi: int):
-        qb = lax.dynamic_slice_in_dim(qg, qi * mq, mq, axis=1)      # (B,mq,KV,G,hd)
-        q_pos = q_offset + qi * mq + jnp.arange(mq)
-        if causal and kv_len is None and static_offset is not None:
-            hi = min(Sk, ((static_offset + (qi + 1) * mq + chunk - 1) // chunk) * chunk)
-        else:
-            hi = Sk
-        lo = 0
-        if sliding_window is not None and prefix_len == 0 and static_offset is not None:
-            lo = max(0, ((static_offset + qi * mq - sliding_window) // chunk) * chunk)
-        n_chunks = (hi - lo) // chunk
-        kv_slice_k = lax.dynamic_slice_in_dim(k, lo, hi - lo, axis=1)
-        kv_slice_v = lax.dynamic_slice_in_dim(v, lo, hi - lo, axis=1)
-        ks = kv_slice_k.reshape(B, n_chunks, chunk, KV, hd)
-        vs = kv_slice_v.reshape(B, n_chunks, chunk, KV, hd)
-
-        def body(carry, inp):
-            m, l, acc = carry
-            kc, vc, ci = inp                                        # (B,chunk,KV,hd)
-            if kv_pos is not None:
-                k_pos = jnp.take(kv_pos, lo + ci * chunk + jnp.arange(chunk))
-                k_valid = k_pos >= 0
-            else:
-                k_pos = lo + ci * chunk + jnp.arange(chunk)
-                k_valid = None
-            s = jnp.einsum("bqngd,bsnd->bnqgs", qb, kc,
-                           preferred_element_type=jnp.float32) * scale
-            if softcap:
-                s = jnp.tanh(s / softcap) * softcap
-            mask = _chunk_mask(q_pos, k_pos, causal=causal,
-                               sliding_window=sliding_window,
-                               prefix_len=prefix_len, k_valid=k_valid)
-            if kv_len is not None and kv_pos is None:
-                mask = mask & (k_pos[None, :] < kv_len)
-            # s: (B, KV, mq, G, chunk); mask broadcasts over B, KV, G
-            s = jnp.where(mask[None, None, :, None, :], s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m_new[..., None])
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=-1)
-            acc_new = acc * corr[..., None] + jnp.einsum(
-                "bnqgs,bsnd->bnqgd", p.astype(vc.dtype), vc,
-                preferred_element_type=jnp.float32)
-            return (m_new, l_new, acc_new), None
-
-        m0 = jnp.full((B, KV, mq, G), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((B, KV, mq, G), jnp.float32)
-        a0 = jnp.zeros((B, KV, mq, G, hd), jnp.float32)
-        ks_t = ks.swapaxes(0, 1)
-        vs_t = vs.swapaxes(0, 1)
-        (m, l, acc), _ = lax.scan(
-            body, (m0, l0, a0),
-            (ks_t, vs_t, jnp.arange(n_chunks)))
-        out = acc / jnp.maximum(l, 1e-30)[..., None]                 # (B,KV,mq,G,hd)
-        return out.transpose(0, 2, 1, 3, 4).reshape(B, mq, H, hd)
-
-    outs = [one_macro(i) for i in range(n_macro)]
-    out = jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
-    return out.astype(q.dtype)
-
 
 def naive_attention(q, k, v, *, causal=True, sliding_window=None, prefix_len=0,
                     q_offset=0, kv_len=None, kv_pos=None, softcap: float = 0.0):
@@ -244,7 +127,7 @@ def naive_attention(q, k, v, *, causal=True, sliding_window=None, prefix_len=0,
     q_pos = q_offset + jnp.arange(Sq)
     k_pos = jnp.arange(Sk) if kv_pos is None else kv_pos
     k_valid = None if kv_pos is None else kv_pos >= 0
-    mask = _chunk_mask(q_pos, k_pos, causal=causal,
+    mask = chunk_mask(q_pos, k_pos, causal=causal,
                        sliding_window=sliding_window, prefix_len=prefix_len,
                        k_valid=k_valid)
     if kv_len is not None and kv_pos is None:
@@ -377,19 +260,25 @@ def _dyn_update(buf, new, idx):
 
 
 def _attend(cfg, q, k, v, **kw):
+    """Route one attention call.  Uncached self-attention (no KV cache, a
+    static zero offset, no soft-cap, no prefix) runs in the Pallas flash
+    kernel where the kernels run compiled (a TPU) and the step is not
+    partitioned over a mesh (GSPMD cannot split the kernel's custom call;
+    it would gather q, k and v onto every chip).  Every other call takes
+    the XLA flash scan; ``attn_impl="xla_naive"`` and tiny shapes take
+    the reference."""
     if cfg.attn_impl == "xla_naive" or q.shape[1] * k.shape[1] <= 256 * 256:
         return naive_attention(q, k, v, softcap=cfg.logits_softcap, **kw)
-    if cfg.attn_impl == "pallas":
-        from repro.kernels import ops as kops
-        if kw.get("kv_len") is None and kw.get("kv_pos") is None and \
-                kw["q_offset"] == 0 and kw.get("prefix_len", 0) == 0 and \
-                cfg.logits_softcap == 0.0:
-            return kops.flash_attention(q, k, v, causal=kw["causal"],
-                                        sliding_window=kw.get("sliding_window"))
-        # fall through for cached paths
-    # dynamic q_offset (cached prefill/decode) -> single macro block
-    n_macro = 8 if isinstance(kw.get("q_offset"), int) else 1
+    from repro.kernels import ops as kops
     q_offset = kw.pop("q_offset")
+    if (kw.get("kv_len") is None and kw.get("kv_pos") is None and
+            isinstance(q_offset, int) and q_offset == 0 and
+            kw.get("prefix_len", 0) == 0 and cfg.logits_softcap == 0.0 and
+            kops.kernels_compiled() and not ctx.partitioned()):
+        return kops.flash_attention(q, k, v, causal=kw["causal"],
+                                    sliding_window=kw.get("sliding_window"))
+    # dynamic q_offset (cached prefill/decode) -> single macro block
+    n_macro = 8 if isinstance(q_offset, int) else 1
     return flash_attention_xla(q, k, v, chunk=cfg.attn_chunk, n_macro=n_macro,
                                q_offset=q_offset, softcap=cfg.logits_softcap, **kw)
 

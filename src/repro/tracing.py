@@ -12,6 +12,13 @@ device-to-host fetch (``to_host``).
 ``EngineCounters`` are plain cumulative integers owned by the fleet
 engine (``ShardedPlanGroupEngine.counters``); a reader takes their
 difference over the interval it cares about.
+
+Device programs and kernels carry stable names too: the plan's tier
+steps ``jit_plan_<stage>``, the scan ``jit_temporal_scan``, the spatial
+kernels ``spatial_stats``/``spatial_stats_rows``, and the attention
+kernel ``flash_attention`` (an op of the filter step ``jit_filter_step``;
+its presence there is how a trace shows that the filter trunk's
+attention ran in the Pallas kernel rather than the XLA scan).
 """
 from __future__ import annotations
 

@@ -134,3 +134,73 @@ def test_sharded_group_spatial_step_compiles(topo, monkeypatch):
     # the names the device trace shows: the tier's program, the kernel
     assert hlo.startswith("HloModule jit_plan_spatial")
     assert "spatial_stats" in hlo
+
+
+# Temporary bytes of the XLA flash scan's call at S = 3136 (bidirectional,
+# chunk 512, eight query macro-blocks), compiled for the same chip: its
+# float32 score blocks, for (head dim, batch).
+XLA_FLASH_TEMP_BYTES = {(64, 8): 60e6, (64, 32): 600e6,
+                        (128, 8): 292e6, (128, 32): 1422e6}
+
+
+@pytest.mark.parametrize("B_", [8, 32])
+@pytest.mark.parametrize("H,KV,hd", [(14, 2, 64), (24, 2, 128)])
+def test_flash_attention_compiles_at_filter_widths(one_chip, monkeypatch,
+                                                   H, KV, hd, B_):
+    """The filter trunk's attention (Qwen2-0.5B: 14 heads over 2 KV heads
+    of 64; StarCoder2-3B: 24 over 2 of 128) at its 56 x 56 patches, as the
+    projections hand it over: the Pallas kernel, with none of the XLA
+    path's score temporaries.  ``kernels.ops`` asks the backend: steer it
+    to the compiled kernel here."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    S = 3136
+
+    def attend(q, k, v):
+        split = lambda x, n: x.reshape(B_, S, n, hd)
+        return ops.flash_attention(split(q, H), split(k, KV), split(v, KV),
+                                   causal=False).reshape(B_, S, H * hd)
+
+    q = _shape((B_, S, H * hd), jnp.bfloat16, one_chip)
+    kv = _shape((B_, S, KV * hd), jnp.bfloat16, one_chip)
+    compiled = jax.jit(attend).lower(q, kv, kv).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert "%flash_attention" in hlo        # the kernel's name in the trace
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < XLA_FLASH_TEMP_BYTES[(hd, B_)] / 10, temp
+
+
+@pytest.mark.parametrize("partitioned", [True, False])
+def test_train_step_attention_path(topo, monkeypatch, partitioned):
+    """A decoder train step at 512 tokens.  Partitioned over a
+    (data, model) mesh of the four chips and traced under its sharder, as
+    the dry-run and ``launch/train.py`` trace it, the attention stays on
+    the XLA scan, which GSPMD splits over batch and heads: no Pallas
+    kernel, whose custom call it would replicate.  The same step on one
+    chip, with no sharder, runs the kernel forward and the scan's VJP
+    back."""
+    import contextlib
+    from jax.sharding import Mesh, SingleDeviceSharding
+    from repro.models.config import ModelConfig, ShapeCell
+    from repro.optim import adamw
+    from repro.train import step as TS
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = ModelConfig(name="t", n_layers=2, d_model=256, n_heads=4,
+                      n_kv_heads=2, head_dim=64, d_ff=512, vocab_size=512,
+                      max_seq_len=512, dtype="bfloat16")
+    cell, opt = ShapeCell("t", 512, 8, "train"), adamw(1e-3)
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(2, 2),
+                ("data", "model"))
+    jitted, plan = TS.jit_step_for_cell(cfg, cell, mesh, opt)
+    state, inputs = plan.abstract_state, plan.abstract_inputs
+    if partitioned:
+        sharder = plan.sharder()
+    else:
+        chip = SingleDeviceSharding(topo.devices[0])
+        state, inputs = jax.tree.map(
+            lambda a: _shape(a.shape, a.dtype, chip), (state, inputs))
+        jitted, sharder = jax.jit(TS.build_train_step(cfg, opt)), \
+            contextlib.nullcontext()
+    with sharder:
+        hlo = jitted.lower(state, inputs).compile().as_text()
+    assert ("%flash_attention" in hlo) is not partitioned

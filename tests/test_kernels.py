@@ -17,6 +17,14 @@ ATTN_SHAPES = [
     (1, 128, 128, 4, 4, 32),
     (2, 256, 256, 8, 2, 64),
     (1, 512, 512, 4, 1, 128),
+    # unaligned sequences (not multiples of 128) at the served GQA groups:
+    # Qwen2-0.5B (7 query heads per KV head of 64), StarCoder2-3B (12 of 128)
+    (1, 196, 196, 14, 2, 64),
+    (1, 392, 392, 24, 2, 128),
+    # narrow heads that do not fill their last lane tile (5 x 64 lanes)
+    (1, 256, 256, 5, 1, 64),
+    # heads wider than one lane tile (PaliGemma-3B's 256)
+    (1, 384, 384, 8, 1, 256),
 ]
 
 
@@ -36,12 +44,46 @@ def test_flash_attention_sweep(shape, dtype, causal):
                                want.astype(jnp.float32), atol=atol)
 
 
-@pytest.mark.parametrize("sw", [32, 128])
-def test_flash_attention_sliding(sw):
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_grad_is_xla_flash_grad(causal):
+    """The kernel's custom_vjp: its backward is that of the XLA flash path."""
+    from repro.models.layers import flash_attention_xla
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    q = _rand(ks[0], (1, 96, 4, 64), jnp.float32)
+    k = _rand(ks[1], (1, 96, 2, 64), jnp.float32)
+    v = _rand(ks[2], (1, 96, 2, 64), jnp.float32)
+    w = _rand(ks[3], (1, 96, 4, 64), jnp.float32)
+
+    def grads(attn):
+        return jax.grad(lambda q, k, v: jnp.sum(attn(q, k, v, causal=causal)
+                                                * w), argnums=(0, 1, 2))(
+            q, k, v)
+
+    for got, want in zip(grads(ops.flash_attention),
+                         grads(flash_attention_xla)):
+        assert float(jnp.max(jnp.abs(want))) > 0
+        np.testing.assert_allclose(got, want, atol=1e-6)
+
+
+def test_flash_attention_rejects_ragged_head_dim():
+    q = jnp.zeros((1, 256, 2, 80))
+    with pytest.raises(ValueError, match="head dim 80"):
+        ops.flash_attention(q, q, q)
+
+
+@pytest.mark.parametrize("sw,S,H,KV,hd", [
+    (32, 256, 4, 2, 32),
+    (128, 256, 4, 2, 32),
+    # 600 keys pad to two blocks of 384: the window and the padded keys
+    # masked together, at the served GQA groups and both lane layouts
+    (128, 600, 14, 2, 64),
+    (256, 600, 24, 2, 128),
+])
+def test_flash_attention_sliding(sw, S, H, KV, hd):
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = _rand(ks[0], (1, 256, 4, 32), jnp.float32)
-    k = _rand(ks[1], (1, 256, 2, 32), jnp.float32)
-    v = _rand(ks[2], (1, 256, 2, 32), jnp.float32)
+    q = _rand(ks[0], (1, S, H, hd), jnp.float32)
+    k = _rand(ks[1], (1, S, KV, hd), jnp.float32)
+    v = _rand(ks[2], (1, S, KV, hd), jnp.float32)
     out = ops.flash_attention(q, k, v, causal=True, sliding_window=sw)
     want = ref.flash_attention_ref(q, k, v, causal=True, sliding_window=sw)
     np.testing.assert_allclose(out, want, atol=1e-4)

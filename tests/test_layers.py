@@ -108,3 +108,50 @@ def test_norms(tiny_dense):
     p2 = L.norm_init(cfg_ln)
     y2 = L.apply_norm(p2, x, 1e-6)
     np.testing.assert_allclose(jnp.mean(y2, -1), jnp.zeros((2, 4)), atol=1e-4)
+
+
+# _attend's routing, case -> (config changes, call changes, path on a TPU)
+ATTEND_ROUTES = {
+    "bidirectional": ({}, {}, "kernel"),
+    "causal": ({}, {"causal": True}, "kernel"),
+    "cached": ({}, {"kv_len": 384, "q_offset": jnp.int32(0)}, "xla"),
+    "ring_cache": ({}, {"kv_pos": jnp.arange(384)}, "xla"),
+    "softcap": ({"logits_softcap": 30.0}, {}, "xla"),
+    "prefix_lm": ({}, {"causal": True, "prefix_len": 16}, "xla"),
+    "xla_naive": ({"attn_impl": "xla_naive"}, {}, "naive"),
+    # traced under a step factory's activation sharder: partitioned
+    "partitioned": ({}, {}, "xla"),
+}
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+@pytest.mark.parametrize("case", list(ATTEND_ROUTES))
+def test_attend_routing(monkeypatch, tiny_dense, case, backend):
+    """Uncached self-attention takes the Pallas flash kernel on a TPU;
+    cached, soft-capped, prefix-LM and ``xla_naive`` calls, calls of a
+    partitioned step, and every call on the CPU, keep their XLA paths."""
+    import contextlib
+    import dataclasses
+    from repro.distributed import ctx
+    from repro.kernels import ops
+    cfg_kw, call_kw, on_tpu = ATTEND_ROUTES[case]
+    cfg = dataclasses.replace(tiny_dense, **{"attn_impl": "flash", **cfg_kw})
+    taken = []
+
+    def spy(path):
+        return lambda q, k, v, **kw: taken.append(path) or jnp.zeros_like(q)
+    monkeypatch.setattr(ops, "flash_attention", spy("kernel"))
+    monkeypatch.setattr(L, "flash_attention_xla", spy("xla"))
+    monkeypatch.setattr(L, "naive_attention", spy("naive"))
+    if backend == "tpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jnp.zeros((1, 384, 4, 16))
+    k = jnp.zeros((1, 384, 2, 16))
+    kw = dict(causal=False, kv_len=None, kv_pos=None, q_offset=0,
+              sliding_window=None, prefix_len=0)
+    sharder = (ctx.activation_sharder(lambda x, kind: x)
+               if case == "partitioned" else contextlib.nullcontext())
+    with sharder:
+        L._attend(cfg, q, k, k, **{**kw, **call_kw})
+    want = on_tpu if backend == "tpu" or on_tpu == "naive" else "xla"
+    assert taken == [want]
