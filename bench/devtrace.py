@@ -6,9 +6,13 @@ reads the ``.xplane.pb`` with JAX's own ``ProfileData`` and keeps
 
 - every event of a device plane (``/device:TPU:<n>``): its line ("XLA
   Ops", "XLA Modules", ...), name (an op's HLO text), start and duration;
-- the host spans the benchmark itself writes (names starting ``bench.``).
+- the host spans the benchmark itself writes (names starting ``bench.``);
+- the program's own host spans (names starting ``repro.``; see
+  ``repro.tracing``).
 
-``Trace`` then answers, over the window span ``bench.window``:
+``Trace`` keeps the two kinds of host span apart (``host``: the
+benchmark's; ``program``: the program's, inside the window) and answers,
+over the window span ``bench.window``:
 
 - busy seconds per device: the union of the intervals in which an op ran
   (line "XLA Ops");
@@ -31,6 +35,8 @@ DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):(\d+)")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 WINDOW_SPAN = "bench.window"
+BENCH_PREFIX = "bench."
+PROGRAM_PREFIX = "repro."
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,7 +68,8 @@ def from_profile(pd) -> List[Event]:
         device = bool(DEVICE_PLANE.match(plane.name))
         for line in plane.lines:
             for e in line.events:
-                if device or e.name.startswith("bench."):
+                if device or e.name.startswith((BENCH_PREFIX,
+                                                 PROGRAM_PREFIX)):
                     out.append(Event(plane.name, line.name, e.name,
                                      float(e.start_ns),
                                      float(e.duration_ns)))
@@ -126,7 +133,8 @@ def custom_call(e: Event) -> Optional[Tuple[List[Tuple[str, Tuple[int, ...]]],
 
 
 class Trace:
-    """One traced window's device events and benchmark host spans."""
+    """One traced window's device events, benchmark host spans and
+    program spans."""
 
     def __init__(self, events: Sequence[Event],
                  devices: Optional[Sequence[int]] = None):
@@ -143,8 +151,13 @@ class Trace:
             planes = [p for p in planes
                       if int(DEVICE_PLANE.match(p).group(2)) in devices]
         self.planes = planes
-        self.host = [e for e in self.events if e.name.startswith("bench.")
+        self.host = [e for e in self.events
+                     if e.name.startswith(BENCH_PREFIX)
                      and e.name != WINDOW_SPAN]
+        self.program = [e for e in self.events
+                        if e.name.startswith(PROGRAM_PREFIX)
+                        and not DEVICE_PLANE.match(e.plane)
+                        and self.lo <= e.start_ns and e.end_ns <= self.hi]
 
     @property
     def window_s(self) -> float:

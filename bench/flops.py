@@ -7,21 +7,24 @@ are what a kernel must read and write in HBM at least once.
 """
 from __future__ import annotations
 
+from pathlib import Path
 from typing import Any, Dict
 
-from bench.arch import trunk
+from bench import arch as A
 
 
-def trunk_layer_flops(cfg: Dict[str, Any], tokens: int) -> float:
-    """One pre-norm block over ``tokens`` positions attending to all of
-    them: the Q/K/V/O projections, scores and weighted values, the MLP."""
+def trunk_layer_flops(cfg: Dict[str, Any], tokens: int,
+                      gated_mlp: bool) -> float:
+    """One dense pre-norm block over ``tokens`` positions attending to all
+    of them: the Q/K/V/O projections, scores and weighted values, the MLP
+    (three matmuls where ``gated_mlp``, else two)."""
     d = cfg["hidden_size"]
     H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     hd = d // H
     f = cfg["intermediate_size"]
     proj = 2 * d * (H * hd + 2 * KV * hd) + 2 * H * hd * d
     attend = 2 * 2 * tokens * H * hd
-    mlp = 2 * d * f * (3 if trunk(cfg)["gated_mlp"] else 2)
+    mlp = 2 * d * f * (3 if gated_mlp else 2)
     return float(tokens * (proj + attend + mlp))
 
 
@@ -38,12 +41,14 @@ def head_flops(cfg: Dict[str, Any]) -> float:
     raise ValueError(fl["head"])
 
 
-def filter_flops_per_frame(cfg: Dict[str, Any], d_in: int) -> float:
+def filter_flops_per_frame(cfg: Dict[str, Any], d_in: int,
+                           root: Path = A.ROOT) -> float:
     """The filter forward of one frame: input projection, the trunk's
-    layers up to the tap, the head."""
+    layers up to the tap (counted by the configuration's architecture
+    file under ``root``), the head."""
     g2 = cfg["filter"]["grid"] ** 2
     return (2.0 * g2 * d_in * cfg["hidden_size"]
-            + cfg["num_hidden_layers"] * trunk_layer_flops(cfg, g2)
+            + A.load(cfg, root).trunk_flops(cfg, g2)
             + head_flops(cfg))
 
 

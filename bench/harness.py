@@ -7,6 +7,8 @@ cell or per-layer metric is a file of its own, found by name:
 - ``BENCHMARK.json`` names each cell's configuration and traffic;
 - ``bench/configs/<config>.json`` (and the plain reference it names,
   ``bench/configs/<reference>.py``);
+- ``bench/archs/<model_type>.py``: the trunk architecture a
+  configuration's ``model_type`` names (``bench/arch.py``);
 - ``bench/traffic/<traffic>.json``: scene, cameras, arrival mode, window
   and chunk, the query mix;
 - ``bench/mixes/<mix>.json``: the queries;
@@ -24,7 +26,6 @@ exists, and runs the filter step on them.
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import json
 import math
 import sys
@@ -37,7 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import answers as A
-from bench.model import filter_step_fn, flat_params, make_params, model_config
+from bench.arch import load, load_module
+from bench.model import filter_step_fn, flat_params, make_params
 from bench.seeds import host_rng
 from bench.traffic import Schedule, Scene, make_footage
 
@@ -107,21 +109,14 @@ def make_cell(name: str, config_file: Path, traffic: str, chips: int,
 
 
 def load_reader(metric: str, root: Path = ROOT) -> Callable:
-    path = root / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(root / "bench" / "metrics" / f"{metric}.py",
+                       f"bench_metric_{metric.replace('.', '_')}").read
 
 
 def load_reference(cell: Cell):
-    path = cell.root / "bench" / "configs" / f"{cell.config['reference']}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_reference_{cell.config['reference']}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    ref = cell.config["reference"]
+    return load_module(cell.root / "bench" / "configs" / f"{ref}.py",
+                       f"bench_reference_{ref}")
 
 
 def peaks(kind: str, root: Path = ROOT) -> Dict[str, float]:
@@ -179,13 +174,14 @@ class Fleet:
         t, cfg = cell.traffic, cell.config
         self.cell = cell
         self.seed = seed
-        self.mcfg = model_config(cfg)
+        self.arch = load(cfg, cell.root)
+        self.mcfg = self.arch.model_config(cfg)
         self.d_in = cfg["filter"]["d_embed"]
         self.batch = int(t["chunk"])
         self.window = int(t["window"])
         self.n_cameras = int(t["cameras"])
         t0 = time.perf_counter()
-        self.params = make_params(self.mcfg, self.d_in, seed)
+        self.params = make_params(self.mcfg, self.d_in, seed, self.arch)
         jax.block_until_ready(self.params)
         t1 = time.perf_counter()
         scene = Scene.from_json(t["scene"])
@@ -408,13 +404,16 @@ def check(fleet: Fleet, rec: Served, limits: Dict[str, float],
 
 @dataclasses.dataclass
 class Run:
-    """What a per-layer reader reads: the cell, the window's counters,
-    the trace (``--trace 1``), the FLOP counts and the chip's peaks."""
+    """What a per-layer reader reads: the cell, the window's counters
+    (``temporal``: the temporal tier's; ``counters``: the change of each
+    field of the fleet engine's ``EngineCounters``), the trace
+    (``--trace 1``), the FLOP counts and the chip's peaks."""
     cell: Cell
     rec: Served
     frames_answered: int
     window_s: float
     temporal: Optional[Dict[str, int]]
+    counters: Dict[str, int]
     trace: Any
     peak: Dict[str, float]
     flops_per_frame: float

@@ -2,11 +2,12 @@
 and the served filter step.
 
 The configuration file (``bench/configs/<name>.json``) keeps the source's
-own key names (a Hugging Face ``config.json``) for the trunk, read through
-``bench/arch.py``, its serving precision (``serve_dtype``) and a ``filter``
-group for the branch the paper taps after layer k.  This module maps it
-onto the program's ``ModelConfig``/``BranchSpec`` and makes the
-weights itself, on the device, in one jitted call from the seed: the
+own key names (a Hugging Face ``config.json``) for the trunk, its serving
+precision (``serve_dtype``) and a ``filter`` group for the branch the paper
+taps after layer k.  Its architecture file (``bench/archs/<model_type>.py``,
+loaded by ``bench/arch.py``) maps it onto the program's
+``ModelConfig``/``BranchSpec``; a dense trunk's mapping is
+``dense_config`` here.  This module makes the weights itself, on the device, in one jitted call from the seed: the
 program's parameter tree (its layout, dtypes and shapes, read with
 ``jax.eval_shape``) filled with the benchmark's own draws, so the plain
 reference can use the very same weights without taking anything the
@@ -17,12 +18,14 @@ from __future__ import annotations
 
 import math
 import zlib
-from typing import Any, Dict
+from pathlib import Path
+from types import ModuleType
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from bench.arch import trunk
+from bench import arch as A
 from bench.seeds import seed32
 from repro.models.config import Activation, BranchSpec, ModelConfig
 from repro.train.filter_train import filter_forward, init_filter_model
@@ -32,11 +35,17 @@ UNUSED = {("trunk", "embed"), ("trunk", "lm_head")}
 NORMS = ("ln1", "ln2", "final_norm")
 
 
-def model_config(cfg: Dict[str, Any]) -> ModelConfig:
-    """The program's config for the layers the filter runs."""
+def model_config(cfg: Dict[str, Any], root: Path = A.ROOT) -> ModelConfig:
+    """The program's config for the layers the filter runs, from the
+    configuration's architecture file under ``root``."""
+    return A.load(cfg, root).model_config(cfg)
+
+
+def dense_config(cfg: Dict[str, Any], arch: Dict[str, Any]) -> ModelConfig:
+    """A dense trunk's ``ModelConfig`` (``family="dense"``) from the
+    published keys and the options ``arch`` its architecture implies."""
     f = cfg["filter"]
     heads = cfg["num_attention_heads"]
-    arch = trunk(cfg)
     if arch["proj_bias"]:
         raise ValueError("the program has no bias on the output and MLP "
                          "projections")
@@ -56,13 +65,18 @@ def model_config(cfg: Dict[str, Any]) -> ModelConfig:
                           head_dim=f["head_dim"]))
 
 
-def _scale(path, shape) -> float:
-    """Standard deviation of a leaf's draw: 1/sqrt(fan-in) for weights,
-    small for biases and positions (norm gains are 1 +- 0.1)."""
+def _scale(path, shape, arch: Optional[ModuleType] = None) -> float:
+    """Standard deviation of a leaf's draw: the architecture's own
+    ``scale`` where it gives one, else 1/sqrt(fan-in) for weights, small
+    for biases and positions (norm gains are 1 +- 0.1)."""
     names = [p.key for p in path]
     leaf = names[-1]
     stacked = names[:2] == ["trunk", "layers"]
     dims = shape[1:] if stacked else shape
+    own = getattr(arch, "scale", None)
+    s = own(names, dims) if own is not None else None
+    if s is not None:
+        return s
     if leaf in ("wq", "wk", "wv", "wi", "wg") or (leaf == "wo" and
                                                    "mlp" in names):
         return 1.0 / math.sqrt(dims[0])
@@ -77,8 +91,11 @@ def _scale(path, shape) -> float:
     return 0.02                                        # pos, head biases
 
 
-def make_params(mcfg: ModelConfig, d_in: int, seed: int):
-    """The filter's weights on the default device, from the seed."""
+def make_params(mcfg: ModelConfig, d_in: int, seed: int,
+                arch: Optional[ModuleType] = None):
+    """The filter's weights on the default device, from the seed; ``arch``
+    is the configuration's architecture module (``bench/arch.load``),
+    asked for the draw of the leaves it adds."""
     spec = mcfg.branch
     shapes = jax.eval_shape(
         lambda k: init_filter_model(k, mcfg, spec, d_in),
@@ -96,7 +113,7 @@ def make_params(mcfg: ModelConfig, d_in: int, seed: int):
             if path[-1].key == "w" and names[-2] in NORMS:
                 x = 1.0 + 0.1 * jax.random.normal(k, s.shape, jnp.float32)
             else:
-                x = _scale(path, s.shape) * jax.random.normal(
+                x = _scale(path, s.shape, arch) * jax.random.normal(
                     k, s.shape, jnp.float32)
             out.append(x.astype(s.dtype))
         return jax.tree_util.tree_unflatten(treedef, out)

@@ -1,34 +1,36 @@
 """The program's own spans and counters over one traced window.
 
 The fleet path opens ``repro.`` spans at each layer boundary and counts
-its work in ``ShardedPlanGroupEngine.counters`` (``repro.tracing``).  The
-harness keeps only the benchmark's ``bench.`` spans from a trace and no
-counters, so no per-layer metric of ``BENCHMARK.json`` reads these yet.
-This tool, run by hand, serves one traced window as ``bench/run.py --trace
-1`` does, prints its result line, and then one JSON line of readings:
+its work in ``ShardedPlanGroupEngine.counters`` (``repro.tracing``).  A
+traced run keeps those spans (``Trace.program``) and the counters' change
+over the window (``Run.counters``); the reductions here turn them into
+the per-layer metrics of the same names, each read by its own
+``bench/metrics/<name>.py``:
 
-    python3 bench/program_spans.py --workload <cell> --seed <n> --seconds <s>
-
-- ``host_fetches_per_chunk``: device-to-host fetches per chunk served
-  (``EngineCounters``, over the window);
+- ``host_fetches_per_chunk``: device-to-host fetches per chunk served;
 - ``prefetch_block_ms``: median of the ``repro.engine.prefetch`` spans,
   the staging of chunk k+1 inside ``run_chunk(k)``;
 - ``engine_idle_ms``: the first device's idle seconds whose innermost open
   span, of the benchmark's and the program's, is a program span, per chunk;
 - ``plan_host_ms``: median over chunks of the staged plan's host self time,
-  the ``repro.plan.`` spans less their ``repro.sync.`` children;
-- ``idle_by_span``: that idle time by the innermost span's name;
+  the ``repro.plan.`` spans less their ``repro.sync.`` children.
+
+Run by hand, it serves one traced window as ``bench/run.py --trace 1``
+does, prints its result line, and then one JSON line with those four
+readings and
+
+- ``idle_by_span``: the idle time by the innermost span's name;
 - ``span_ms``: the median length of each program span, by name;
 - ``counters``: each counter's change over the window;
 - ``traced``: the end-to-end metrics of this traced window, beside those
   of a run with the profiler off, for the profiler's cost.
+
+    python3 bench/program_spans.py --workload <cell> --seed <n> --seconds <s>
 """
 from __future__ import annotations
 
 import contextlib
 import copy
-import dataclasses
-import glob
 import json
 import statistics
 import sys
@@ -38,53 +40,10 @@ from typing import Dict, List, Optional
 ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-import bench.run as R  # noqa: E402  (first: its clock starts the set-up)
 from bench import devtrace as DT  # noqa: E402
 from bench import harness as H  # noqa: E402
 
-PREFIX = "repro."
-
-
-def program_events(trace_dir: str) -> List[DT.Event]:
-    """The program's spans (names starting ``repro.``) of the newest
-    ``.xplane.pb`` under ``trace_dir``."""
-    from jax.profiler import ProfileData
-    path = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb",
-                            recursive=True))[-1]
-    return [DT.Event(plane.name, line.name, e.name, float(e.start_ns),
-                     float(e.duration_ns))
-            for plane in ProfileData.from_file(path).planes
-            for line in plane.lines for e in line.events
-            if e.name.startswith(PREFIX)]
-
-
-@contextlib.contextmanager
-def keep_program(got: Dict):
-    """While open, a traced run leaves in ``got`` the trace's events
-    (``events``), the program's spans (``program``), the window's served
-    pass (``rec``) and the engine's counters before and after it."""
-    load, serve = DT.load, H.Fleet.serve
-
-    def load_keeping(trace_dir):
-        got["program"] = program_events(trace_dir)
-        got["events"] = load(trace_dir)
-        return got["events"]
-
-    def serve_counting(fleet, *args, record=True, **kw):
-        counters = getattr(fleet.engine, "counters", None)
-        before = copy.copy(counters)
-        rec = serve(fleet, *args, record=record, **kw)
-        if record:
-            got.update(rec=rec, live=fleet.cell.live, chips=fleet.cell.chips,
-                       frames_per_chunk=fleet.n_cameras * fleet.batch,
-                       counters=(before, copy.copy(counters)))
-        return rec
-
-    DT.load, H.Fleet.serve = load_keeping, serve_counting
-    try:
-        yield got
-    finally:
-        DT.load, H.Fleet.serve = load, serve
+PREFIX = DT.PROGRAM_PREFIX
 
 
 def _inside(outer: DT.Event, e: DT.Event) -> bool:
@@ -99,9 +58,27 @@ def idle_by_span(trace: DT.Trace, spans: List[DT.Event]) -> Dict[str, float]:
     return dict(both.idle_gaps(len(both.host) + 1))
 
 
+def host_fetches_per_chunk(counters: Optional[Dict[str, int]]
+                           ) -> Optional[float]:
+    if not counters or not counters["chunks"]:
+        return None
+    return counters["host_fetches"] / counters["chunks"]
+
+
 def prefetch_block_ms(spans: List[DT.Event]) -> Optional[float]:
     d = [e.dur_ns for e in spans if e.name == "repro.engine.prefetch"]
     return statistics.median(d) / 1e6 if d else None
+
+
+def engine_idle_ms(trace: DT.Trace, spans: List[DT.Event],
+                   chunks: int) -> Optional[float]:
+    """Idle milliseconds of the first device per chunk under a program
+    span (``idle_by_span``); None where there are no program spans."""
+    if not spans or not chunks:
+        return None
+    idle = idle_by_span(trace, spans)
+    return sum(s for n, s in idle.items()
+               if n.startswith(PREFIX)) / chunks * 1e3
 
 
 def plan_host_ms(spans: List[DT.Event]) -> Optional[float]:
@@ -123,45 +100,48 @@ def span_ms(spans: List[DT.Event]) -> Dict[str, float]:
     return {n: statistics.median(d) for n, d in sorted(by_name.items())}
 
 
-def readings(got: Dict) -> Dict:
-    """The readings of one window that ``keep_program`` recorded."""
-    trace = DT.Trace(got["events"], devices=range(got["chips"]))
-    spans = [e for e in got["program"]
-             if trace.lo <= e.start_ns and e.end_ns <= trace.hi]
-    before, after = got["counters"]
-    delta = ({f.name: getattr(after, f.name) - getattr(before, f.name)
-              for f in dataclasses.fields(after)}
-             if after is not None else None)
-    rec = got["rec"]
-    chunks = len(rec.chunks)
-    idle = idle_by_span(trace, spans)
-    out = {"host_fetches_per_chunk":
-           delta["host_fetches"] / delta["chunks"]
-           if delta and delta["chunks"] else None,
+def readings(run) -> Dict:
+    """The readings of one traced window's ``Run``."""
+    trace, spans = run.trace, run.trace.program
+    chunks = len(run.rec.chunks)
+    out = {"host_fetches_per_chunk": host_fetches_per_chunk(run.counters),
            "prefetch_block_ms": prefetch_block_ms(spans),
-           "engine_idle_ms": sum(s for n, s in idle.items()
-                                 if n.startswith(PREFIX)) / chunks * 1e3
-           if chunks else None,
+           "engine_idle_ms": engine_idle_ms(trace, spans, chunks),
            "plan_host_ms": plan_host_ms(spans),
-           "idle_by_span": idle, "span_ms": span_ms(spans),
-           "counters": delta}
+           "idle_by_span": idle_by_span(trace, spans),
+           "span_ms": span_ms(spans), "counters": run.counters}
     if chunks:
-        traced = {"frames_per_s": chunks * got["frames_per_chunk"]
-                  / (rec.t_end - rec.t0)}
-        if got["live"]:
-            traced["latency_p50_ms"] = H.percentile(rec.latencies_s, 50) * 1e3
-            traced["latency_p95_ms"] = H.percentile(rec.latencies_s, 95) * 1e3
+        traced = H.end_to_end(run, 0.0)
+        traced.pop("setup_s")
         out["traced"] = traced
     return out
 
 
+@contextlib.contextmanager
+def keep_run(got: Dict):
+    """While open, a run leaves the ``Run`` its metrics were read from in
+    ``got["run"]``."""
+    make = H.Run
+
+    def keeping(*args, **kw):
+        got["run"] = make(*args, **kw)
+        return got["run"]
+
+    H.Run = keeping
+    try:
+        yield got
+    finally:
+        H.Run = make
+
+
 def main(argv=None) -> int:
+    import bench.run as R       # the command itself, not loaded by readers
     got: Dict = {}
-    with keep_program(got):
+    with keep_run(got):
         rc = R.main(list(sys.argv[1:] if argv is None else argv)
                     + ["--trace", "1"])
     if rc == 0:
-        print(json.dumps(readings(got)), flush=True)
+        print(json.dumps(readings(got["run"])), flush=True)
     return rc
 
 

@@ -18,6 +18,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
@@ -130,6 +131,8 @@ def measure(cell, seed: int, seconds: float, trace: bool,
     warm = warm_up(fleet, seconds, n if cell.live else None)
     ts = fleet.engine.temporal_stats
     ts0 = (ts.frames_in, ts.frames_skipped) if ts is not None else (0, 0)
+    ec = fleet.engine.counters
+    ec0 = dataclasses.asdict(ec)
     # The warmed-up heap (JAX's caches and traced programs, the footage,
     # the plan) is collected once and frozen, as a long-running server
     # does after warm-up, so that no full collection walks it inside the
@@ -188,9 +191,12 @@ def measure(cell, seed: int, seconds: float, trace: bool,
                 temporal=None if ts is None else
                 {"frames_in": ts.frames_in - ts0[0],
                  "frames_skipped": ts.frames_skipped - ts0[1]},
+                counters={k: v - ec0[k]
+                          for k, v in dataclasses.asdict(ec).items()},
                 trace=None, peak=H.peaks(devices[0].device_kind, cell.root),
                 flops_per_frame=filter_flops_per_frame(
-                    cell.config, cell.config["filter"]["d_embed"]),
+                    cell.config, cell.config["filter"]["d_embed"],
+                    cell.root),
                 batch=B)
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
@@ -199,6 +205,7 @@ def measure(cell, seed: int, seconds: float, trace: bool,
               "failed": n * S - frames if cell.live else 0}
     if trace:
         from bench import devtrace as DT
+        t_read = time.perf_counter()
         run.trace = DT.Trace(DT.load(trace_dir),
                              devices=range(cell.chips))
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -211,6 +218,8 @@ def measure(cell, seed: int, seconds: float, trace: bool,
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         result["breakdown"] = {"device_ops": run.trace.top_ops(10),
                                "idle_gaps": run.trace.idle_gaps(10)}
+        H.log(f"trace: {len(run.trace.events)} events read and reduced "
+              f"in {time.perf_counter() - t_read:.3f} s")
     else:
         e2e = H.end_to_end(run, setup_s)
         metrics = {m["name"]: {"value": e2e[m["name"]], "unit": m["unit"]}

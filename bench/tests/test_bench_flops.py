@@ -1,5 +1,8 @@
 """The FLOP and byte counts against counts worked out by hand from the
 shapes of the configurations as run."""
+import pytest
+
+from bench import arch as A
 from bench import flops as FL
 from bench import harness as H
 
@@ -13,7 +16,7 @@ def test_qwen2_filter_flops():
     #   Q,K,V,O projections 2*896*(896+128+128) + 2*896*896 =  3,670,016
     #   scores and values   2*2*3136*896                    = 11,239,424
     #   MLP                 3 * 2*896*4864                  = 26,148,864
-    assert FL.trunk_layer_flops(QWEN, 3136) == 3136 * 41_058_304
+    assert FL.trunk_layer_flops(QWEN, 3136, True) == 3136 * 41_058_304
     # input projection 2*3136*64*896 = 359,661,568; 5 layers; IC head
     # 3136*(2*896*256 + 2*256*8) = 1,451,491,328
     assert FL.filter_flops_per_frame(QWEN, 64) == \
@@ -23,12 +26,22 @@ def test_qwen2_filter_flops():
 def test_starcoder2_filter_flops():
     # per token, one layer (d 3072, 24 heads of 128, 2 KV heads, d_ff
     # 12288, plain GELU MLP): 40,894,464 + 38,535,168 + 150,994,944
-    assert FL.trunk_layer_flops(STAR, 3136) == 3136 * 230_424_576
+    assert FL.trunk_layer_flops(STAR, 3136, False) == 3136 * 230_424_576
     # OD head per frame: 3136 * (2*3072*512 + 2*9*512*256 + 2*256*512
     # + 2*512*8) = 18,111,528,960
     assert FL.head_flops(STAR) == 18_111_528_960
     assert FL.filter_flops_per_frame(STAR, 64) == \
         2 * 3136 * 64 * 3072 + 6 * 3136 * 230_424_576 + 18_111_528_960
+
+
+@pytest.mark.parametrize("cfg", [QWEN, STAR], ids=["qwen2", "starcoder2"])
+def test_dense_trunk_flops_are_layers_times_one_layer(cfg):
+    """A dense architecture file counts its trunk as its layers times one
+    layer of the shared helper, gated as its ``trunk`` says."""
+    arch = A.load(cfg)
+    for tokens in (1, 3136):
+        assert arch.trunk_flops(cfg, tokens) == cfg["num_hidden_layers"] \
+            * FL.trunk_layer_flops(cfg, tokens, arch.trunk(cfg)["gated_mlp"])
 
 
 def test_kernel_costs_and_roofline():

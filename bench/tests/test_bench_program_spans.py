@@ -1,6 +1,7 @@
 """``bench/program_spans.py``: its reductions of the program's spans
 against values worked out by hand, and a smoke-size traced run of the live
-cell on the CPU that finds the program's spans and counters."""
+cell on the CPU that finds the program's spans and counters, read by the
+per-layer metrics of the same names through the run's ``Run``."""
 import time
 
 import pytest
@@ -46,6 +47,13 @@ def window():
     return trace, [e for e in ev if e.name.startswith("repro.")]
 
 
+def test_trace_keeps_program_spans_apart(window):
+    trace, spans = window
+    assert trace.program == spans
+    assert {e.name for e in trace.host} == {"bench.run_chunk",
+                                            "bench.fetch_wait"}
+
+
 def test_idle_by_program_span(window):
     trace, spans = window
     # per chunk the device idles [0, 15), [25, 32) and [37, 95); the
@@ -59,6 +67,18 @@ def test_idle_by_program_span(window):
     # the benchmark's own attribution is untouched
     assert dict(trace.idle_gaps()) == pytest.approx(
         {"bench.fetch_wait": 45e-9, "bench.run_chunk": 195e-9})
+    # per chunk 7 + 58 ns under program spans, over 3 chunks
+    assert PS.engine_idle_ms(trace, spans, 3) == pytest.approx(65e-6)
+    assert PS.engine_idle_ms(trace, [], 3) is None
+    assert PS.engine_idle_ms(trace, spans, 0) is None
+
+
+def test_host_fetches_per_chunk():
+    assert PS.host_fetches_per_chunk({"chunks": 4, "host_fetches": 66}) \
+        == 16.5
+    assert PS.host_fetches_per_chunk({"chunks": 0, "host_fetches": 0}) \
+        is None
+    assert PS.host_fetches_per_chunk(None) is None
 
 
 def test_prefetch_and_plan_host_time(window):
@@ -79,22 +99,33 @@ def cpu_peaks(monkeypatch):
         "bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
 
 
+READERS = ("host_fetches_per_chunk", "prefetch_block_ms", "engine_idle_ms",
+           "plan_host_ms")
+
+
 def test_traced_live_run_reads_program_spans(cpu_peaks):
     cell = tiny_cell("qwen2-0.5b.live-detrac")
+    assert set(READERS) <= {m["name"] for m in cell.per_layer}
     got = {}
-    with PS.keep_program(got):
+    with PS.keep_run(got):
         res = R.measure(cell, 2 ** 31 + 17, 1.0, True, time.perf_counter())
-    assert DT.load.__name__ == "load" and H.Fleet.serve.__name__ == "serve"
+    assert H.Run.__name__ == "Run"
     assert res["correct"], res["checks"]
-    out = PS.readings(got)
+    run = got["run"]
+    out = PS.readings(run)
+    # each per-layer metric of the result line is its reduction's reading
+    for name in READERS:
+        assert res["metrics"][name]["value"] == out[name], name
+        assert H.load_reader(name)(run) == out[name]
     c = out["counters"]
-    chunks = len(got["rec"].chunks)
+    assert c == run.counters
+    chunks = len(run.rec.chunks)
     assert c["chunks"] == chunks > 1 and c["steps_built"] == 0
     assert c["prefetch_hits"] + c["prefetch_misses"] == chunks
     # at least the answer, the plan's counts and the scan's 12 arrays
     assert out["host_fetches_per_chunk"] == c["host_fetches"] / chunks >= 14
     assert out["prefetch_block_ms"] > 0 and out["plan_host_ms"] > 0
-    names = {e.name for e in got["program"]}
+    names = {e.name for e in run.trace.program}
     assert {"repro.executor.chunk", "repro.engine.run_chunk",
             "repro.sync.answer", "repro.temporal.advance"} <= names
     assert set(out["traced"]) == {"frames_per_s", "latency_p50_ms",

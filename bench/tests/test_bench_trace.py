@@ -96,3 +96,26 @@ def test_union_by_hand():
         {"none": 10e-9, "bench.fetch_wait": 20e-9})
     assert dict(tr.top_ops()) == pytest.approx(
         {":a": 10e-9, ":b": 10e-9, ":c": 50e-9})
+
+
+def test_from_profile_keeps_device_events_and_both_host_spans():
+    """A profile's device events, the benchmark's ``bench.`` spans and the
+    program's ``repro.`` spans are kept; other host events are not."""
+    from types import SimpleNamespace as NS
+
+    def plane(name, line, *events):
+        return NS(name=name, lines=[NS(name=line, events=[
+            NS(name=n, start_ns=s, duration_ns=d) for n, s, d in events])])
+
+    pd = NS(planes=[
+        plane("/device:TPU:0", "XLA Ops", ("%fusion.1 = f32[8]", 5, 3)),
+        plane("/host:CPU", "python", ("bench.window", 0, 100),
+              ("repro.engine.run_chunk", 10, 20), ("PjitFunction", 12, 1),
+              ("bench.fetch_wait", 40, 5))])
+    ev = DT.from_profile(pd)
+    assert [e.name for e in ev] == ["%fusion.1 = f32[8]", "bench.window",
+                                    "repro.engine.run_chunk",
+                                    "bench.fetch_wait"]
+    tr = DT.Trace(ev)
+    assert [e.name for e in tr.host] == ["bench.fetch_wait"]
+    assert [e.name for e in tr.program] == ["repro.engine.run_chunk"]
